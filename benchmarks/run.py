@@ -1,4 +1,4 @@
-"""Benchmark driver: one module per paper table/figure + the roofline.
+"""Benchmark driver: one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (benchmarks/common.emit).
 
@@ -11,7 +11,7 @@ import sys
 from benchmarks import (fig2_component_speedup, fig7_throughput_onprem,
                         fig8_throughput_aws, fig9_pp_comparison,
                         fig10_gpu_ratios, fig11_homogeneous, fig12_asym_ea,
-                        roofline, table3_utilization)
+                        table3_utilization)
 
 BENCHES = {
     "fig2": fig2_component_speedup.main,
@@ -22,7 +22,6 @@ BENCHES = {
     "fig11": fig11_homogeneous.main,
     "fig12": fig12_asym_ea.main,
     "table3": table3_utilization.main,
-    "roofline": roofline.main,
 }
 
 

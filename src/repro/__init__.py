@@ -6,7 +6,7 @@ Public surface:
     repro.core      — zebra parallelism, Asym-EA, planner, simulator
     repro.train     — training loop, optimizer, mixed precision
     repro.serve     — KV-cache serving
-    repro.launch    — mesh / dryrun / train / serve entry points
+    repro.launch    — mesh / compile cache / train / serve entry points
 """
 
 __version__ = "0.1.0"

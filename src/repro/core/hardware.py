@@ -52,11 +52,6 @@ TPU_V3 = DeviceClass("tpu-v3", 123e12, 900e9, 32e9, False, 0.50, 0.0, 0.14,
 CLASSES = {c.name: c for c in
            [V100, A40, T4, L40S, A100, TPU_V5E, TPU_V4, TPU_V3]}
 
-# Roofline constants for the target deployment (per the brief).
-ROOFLINE_PEAK_FLOPS = 197e12   # TPU v5e bf16
-ROOFLINE_HBM_BW = 819e9
-ROOFLINE_ICI_BW = 50e9         # per link
-
 
 def get(name: str) -> DeviceClass:
     return CLASSES[name]

@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.models import modules
 from repro.models.config import LayerSpec, ModelConfig
 from repro.models.modules import RunConfig
@@ -293,7 +292,8 @@ def make_ep_moe(mesh: Mesh, cfg: ModelConfig, run: RunConfig,
             if n_loc:
                 fp[k_ + "_loc"] = ffn_params[k_][:n_loc]
             fp[k_] = ffn_params[k_][n_loc:]
-        sm = _shard_map(fn, mesh, in_specs, out_specs)
+        sm = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return sm(fp, x2d)
 
     return moe_fn

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 DEFAULT_BLOCK_Q = 128
@@ -95,10 +93,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
 
     @pl.when(ik == n_k - 1)
     def _finish():
-        l = l_s[:, 0]
+        l = l_s[...]  # [bq, 1]
         denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc[...] / denom[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(l == 0.0, _NEG, m_s[:, 0] + jnp.log(denom))
+        o_ref[0, 0] = (acc[...] / denom).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.where(l == 0.0, _NEG, m_s[...] + jnp.log(denom))
 
 
 def flash_forward(q, k, v, *, scale, causal, window, softcap,
@@ -106,8 +104,12 @@ def flash_forward(q, k, v, *, scale, causal, window, softcap,
                   block_k=DEFAULT_BLOCK_K, interpret=False):
     """q: [B,H,Sq,hd]; k/v: [B,KH,Skv,hd] (pre-padded to block multiples).
 
-    Returns (o [B,H,Sq,hd], lse [B,H,Sq] f32). ``q_len``/``kv_len`` are the
-    *true* (unpadded) lengths used for masking; default = padded shapes.
+    Returns (o [B,H,Sq,hd], lse [B,H,Sq,1] f32). ``q_len``/``kv_len`` are
+    the *true* (unpadded) lengths used for masking; default = padded shapes.
+    The trailing unit dim of ``lse`` keeps its block ``(1,1,block_q,1)``
+    legal for Mosaic: the last two block dims must be divisible by (8, 128)
+    or equal the array's, which a ``(1,1,block_q)`` block over ``[B,H,Sq]``
+    is not.
     """
     B, H, Sq, hd = q.shape
     KH, Skv = k.shape[1], k.shape[2]
@@ -131,18 +133,19 @@ def flash_forward(q, k, v, *, scale, causal, window, softcap,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, hd), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -170,16 +173,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]  # [bq]
-        delta = delta_ref[0, 0]  # [bq]
+        lse = lse_ref[0, 0]  # [bq, 1]
+        delta = delta_ref[0, 0]  # [bq, 1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         mask = _block_mask(iq * bq, ik * bk, bq, bk, q_len, kv_len,
                            causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dq_acc[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -213,12 +216,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                 preferred_element_type=jnp.float32) * scale
         mask = _block_mask(iq * bq, ik * bk, bq, bk, q_len, kv_len,
                            causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)  # [bq, bk]
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # [bq, bk]
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -240,7 +243,7 @@ def flash_backward(q, k, v, o, lse, do, *, scale, causal, window,
     q_len = q_len or Sq
     kv_len = kv_len or Skv
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)  # [B,H,Sq]
+                    axis=-1, keepdims=True)  # [B,H,Sq,1]
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -253,14 +256,16 @@ def flash_backward(q, k, v, o, lse, do, *, scale, causal, window,
             pl.BlockSpec((1, 1, block_k, hd),
                          lambda b, h, iq, ik: (b, h // G, ik, 0)),
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd),
                                lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -279,8 +284,10 @@ def flash_backward(q, k, v, o, lse, do, *, scale, causal, window,
             pl.BlockSpec((1, 1, block_k, hd),
                          lambda b, h, ik, iq: (b, h // G, ik, 0)),
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, ik, iq: (b, h, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, ik, iq: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, ik, iq: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, ik, iq: (b, h, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, hd), lambda b, h, ik, iq: (b, h, ik, 0)),
@@ -292,7 +299,7 @@ def flash_backward(q, k, v, o, lse, do, *, scale, causal, window,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                         pltpu.VMEM((block_k, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

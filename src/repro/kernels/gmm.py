@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _gmm_kernel(tile_group, lhs_ref, rhs_ref, out_ref, acc, *, n_k):
     ik = pl.program_id(2)
@@ -82,7 +80,7 @@ def gmm_tiled(lhs, rhs, tile_group, *, block_m=128, block_k=128, block_n=128,
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tile_group, lhs, rhs)
@@ -239,7 +237,7 @@ def _gmm_glu_call(lhs, rhs_g, rhs_u, tile_group, u_off, N, *, block_m,
                             pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tile_group, lhs, rhs_g, rhs_u)
@@ -313,7 +311,7 @@ def gmm_dw_tiled(lhs, dout, tile_group, n_groups, *, block_m=128, block_k=128,
             scratch_shapes=[pltpu.VMEM((block_k, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n_groups, Kp, Np), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tile_group, lhs, dout)

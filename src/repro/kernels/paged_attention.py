@@ -5,9 +5,15 @@ Flash-style single-token decode over a PAGED KV cache: the physical pool is
 are block-gathered through a scalar-prefetched page table — the same
 prefetched-index contract as ``gmm_glu_tiled``'s ``tile_group`` map, applied
 to the sequential kv dimension of a decode flash kernel. One grid step
-streams ONE physical page into VMEM (its index computed from the prefetched
-table before the body runs, so the DMA pipeline still runs ahead) and folds
-it into the online softmax.
+streams ONE physical page, all KH heads of it, into VMEM (its index computed
+from the prefetched table before the body runs, so the DMA pipeline still
+runs ahead) and folds it into each head's online softmax.
+
+The pool is viewed as ``[n_pages, page_size, KH * hd]`` so that a page is one
+contiguous block whose last two dims are the array's own: Mosaic requires
+the last two block dims to be divisible by (8, 128) or to equal the array's,
+and a one-head block ``(1, page_size, 1, hd)`` over ``[.., KH, hd]`` is
+neither when KH > 1.
 
 Masking is structural (DESIGN.md §9.2): line ``l`` of table slot ``j`` is key
 position ``j * page_size + l``; positions beyond the slot's query position
@@ -30,16 +36,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _paged_decode_kernel(pt_ref, valid_ref, qpos_ref, q_ref, k_ref, v_ref,
                          o_ref, acc, m_s, l_s, *, scale, softcap, window,
-                         page_size, n_pages_seq):
+                         page_size, n_pages_seq, n_kv_heads, head_dim):
     b = pl.program_id(0)
-    jp = pl.program_id(2)
+    jp = pl.program_id(1)
 
     @pl.when(jp == 0)
     def _init():
@@ -52,36 +56,39 @@ def _paged_decode_kernel(pt_ref, valid_ref, qpos_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)        # [Gp, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)     # [page_size, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap > 0:
-            s = softcap * jnp.tanh(s / softcap)
-        # Structural key positions: line l of table slot jp sits at
-        # jp * page_size + l. Causal frontier + optional sliding window.
-        kpos = jp * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        mask = kpos <= q_pos
-        if window > 0:
-            mask &= (q_pos - kpos) < window
-        s = jnp.where(mask, s, _NEG)
-        m_prev = m_s[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_s[:, 0] = l_s[:, 0] * alpha + jnp.sum(p, axis=-1)
-        acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_s[:, 0] = m_new
+        for h in range(n_kv_heads):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            q = q_ref[0, h].astype(jnp.float32)          # [Gp, hd]
+            k = k_ref[0, :, lanes].astype(jnp.float32)   # [page_size, hd]
+            v = v_ref[0, :, lanes].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if softcap > 0:
+                s = softcap * jnp.tanh(s / softcap)
+            # Structural key positions: line l of table slot jp sits at
+            # jp * page_size + l. Causal frontier + optional sliding window.
+            kpos = jp * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            mask = kpos <= q_pos
+            if window > 0:
+                mask &= (q_pos - kpos) < window
+            s = jnp.where(mask, s, _NEG)
+            m_prev = m_s[h]                                # [Gp, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_s[h] = l_s[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc[h] = acc[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_s[h] = m_new
 
     @pl.when(jp == n_pages_seq - 1)
     def _finish():
-        l = l_s[:, 0]
+        l = l_s[...]
         denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc[...] / denom).astype(o_ref.dtype)
 
 
 def paged_decode_forward(q, k_pool, v_pool, page_table, q_pos, *, scale,
@@ -101,36 +108,34 @@ def paged_decode_forward(q, k_pool, v_pool, page_table, q_pos, *, scale,
     pt = jnp.maximum(page_table, 0).astype(jnp.int32)
     valid = (page_table >= 0).astype(jnp.int32)
     qp = q_pos.astype(jnp.int32)
+    # Free row-major view: one page = one contiguous [page_size, KH*hd] block.
+    k_flat = k_pool.reshape(P, page_size, KH * hd)
+    v_flat = v_pool.reshape(P, page_size, KH * hd)
 
     kernel = functools.partial(
         _paged_decode_kernel, scale=float(scale), softcap=float(softcap),
-        window=int(window), page_size=page_size, n_pages_seq=MP)
+        window=int(window), page_size=page_size, n_pages_seq=MP,
+        n_kv_heads=KH, head_dim=hd)
 
+    page_spec = pl.BlockSpec((1, page_size, KH * hd),
+                             lambda b, jp, pt, vl, qp: (pt[b, jp], 0, 0))
+    q_spec = pl.BlockSpec((1, KH, Gp, hd),
+                          lambda b, jp, pt, vl, qp: (b, 0, 0, 0))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, KH, MP),
-            in_specs=[
-                pl.BlockSpec((1, 1, Gp, hd),
-                             lambda b, h, jp, pt, vl, qp: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, hd),
-                             lambda b, h, jp, pt, vl, qp:
-                             (pt[b, jp], 0, h, 0)),
-                pl.BlockSpec((1, page_size, 1, hd),
-                             lambda b, h, jp, pt, vl, qp:
-                             (pt[b, jp], 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, Gp, hd),
-                                   lambda b, h, jp, pt, vl, qp: (b, h, 0, 0)),
+            grid=(B, MP),
+            in_specs=[q_spec, page_spec, page_spec],
+            out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((Gp, hd), jnp.float32),
-                pltpu.VMEM((Gp, 1), jnp.float32),
-                pltpu.VMEM((Gp, 1), jnp.float32),
+                pltpu.VMEM((KH, Gp, hd), jnp.float32),
+                pltpu.VMEM((KH, Gp, 1), jnp.float32),
+                pltpu.VMEM((KH, Gp, 1), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KH, Gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(pt, valid, qp, q, k_pool, v_pool)
+    )(pt, valid, qp, q, k_flat, v_flat)

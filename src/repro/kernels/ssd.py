@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _ssd_kernel(xbar_ref, la_ref, b_ref, c_ref, y_ref, state_ref, s_scratch,
                 *, n_chunks):
@@ -97,7 +95,7 @@ def ssd_pallas(xbar, la, B, C, n_heads: int, *, chunk=128, interpret=False):
             jax.ShapeDtypeStruct((BH, hd, ns), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((ns, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xbar, la, B, C)

@@ -1,1 +1,1 @@
-"""Launch entry points: mesh construction, dry-run, train and serve CLIs."""
+"""Launch entry points: mesh construction, compile cache, train and serve CLIs."""

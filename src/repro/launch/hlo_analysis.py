@@ -1,4 +1,4 @@
-"""Post-SPMD HLO analysis: collective bytes + roofline terms.
+"""Post-SPMD HLO analysis: collective bytes.
 
 ``compiled.cost_analysis()`` gives FLOPs and HBM bytes but not collective
 traffic; we parse the (per-device, post-partitioning) HLO text and sum the
@@ -11,21 +11,12 @@ are recovered from the RESULT shape + the replica-group size:
     all-reduce / all-to-all / collective-permute: operand = result
 Async pairs (-start/-done) are counted once via the -start op, whose tuple
 result's first element is the operand.
-
-NOTE (cost-analysis caveat, see launch/dryrun.py): XLA's HloCostAnalysis
-counts while-loop bodies ONCE, so FLOPs/bytes of scanned layer stacks are
-under-counted; the dry-run measures an unrolled 1-repeat and 2-repeat
-variant and extrapolates linearly — exact, since every repeat lowers to the
-same body.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import re
 from typing import Dict
-
-from repro.core import hardware as HW
 
 COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                   "collective-permute")
@@ -116,66 +107,3 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
     out["ring_total"] = int(sum(ring[k] for k in COLLECTIVE_OPS))
     return out
 
-
-@dataclasses.dataclass
-class Roofline:
-    """Three-term roofline for one compiled (arch x shape x mesh) cell."""
-
-    flops_per_device: float
-    hbm_bytes_per_device: float
-    collective_bytes_per_device: float
-    n_devices: int
-    model_flops: float  # 6*N_active*D analytical
-
-    peak_flops: float = HW.ROOFLINE_PEAK_FLOPS
-    hbm_bw: float = HW.ROOFLINE_HBM_BW
-    ici_bw: float = HW.ROOFLINE_ICI_BW
-    ici_links: int = 3  # v5e 2D torus: ~3 usable link-pairs per chip
-
-    @property
-    def t_compute(self) -> float:
-        return self.flops_per_device / self.peak_flops
-
-    @property
-    def t_memory(self) -> float:
-        return self.hbm_bytes_per_device / self.hbm_bw
-
-    @property
-    def t_collective(self) -> float:
-        return self.collective_bytes_per_device / (self.ici_bw *
-                                                   self.ici_links)
-
-    @property
-    def bound(self) -> str:
-        terms = {"compute": self.t_compute, "memory": self.t_memory,
-                 "collective": self.t_collective}
-        return max(terms, key=terms.get)
-
-    @property
-    def step_time_lower_bound(self) -> float:
-        """Perfect-overlap model: max of the three terms."""
-        return max(self.t_compute, self.t_memory, self.t_collective)
-
-    @property
-    def useful_flops_fraction(self) -> float:
-        """MODEL_FLOPS / HLO_FLOPs — how much compiled compute is 'useful'
-        (catches remat / capacity-padding / dispatch waste)."""
-        total = self.flops_per_device * self.n_devices
-        return self.model_flops / total if total else 0.0
-
-    @property
-    def mfu_bound(self) -> float:
-        """Model-FLOPs utilization at the roofline lower bound."""
-        denom = (self.step_time_lower_bound * self.n_devices
-                 * self.peak_flops)
-        return self.model_flops / denom if denom else 0.0
-
-    def row(self) -> dict:
-        return {
-            "t_compute_s": self.t_compute,
-            "t_memory_s": self.t_memory,
-            "t_collective_s": self.t_collective,
-            "bound": self.bound,
-            "useful_flops_frac": self.useful_flops_fraction,
-            "mfu_bound": self.mfu_bound,
-        }

@@ -1,38 +1,16 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Importing this module never touches jax device state; meshes are built by
-FUNCTIONS so the dry-run controls XLA_FLAGS before first jax init.
+functions, from ``jax.devices()``.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on jax >= 0.5 (explicit-sharding work).
-
-    On older jax (0.4.x, this container's pin) ``jax.make_mesh`` has no such
-    parameter and every axis already behaves like ``Auto``, so a plain Mesh
-    is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    """Single pod: 16x16 = 256 chips (data, model).
-    Multi-pod: 2x16x16 = 512 chips (pod, data, model) — the pod axis is the
-    DCN boundary (pure DP; experts stay within a pod, DESIGN.md §3.1)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """Arbitrary mesh helper (tests / small runs)."""
+    """Mesh of ``shape`` over named ``axes``; every axis is ``Auto``."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_mesh_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -81,6 +81,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.obs import format_report, write_chrome_trace
@@ -177,7 +178,11 @@ def _prefix_summary(index, alloc, n_prefix_hits: int,
     }
 
 
-def serve_arch(arch: str, args, serve_cfg: ServeConfig = None) -> dict:
+def serve_arch(arch: str, args, serve_cfg: ServeConfig = None,
+               trace: list = None) -> dict:
+    """Serve one arch through the deployment ``args`` describe and return
+    the metrics summary (``ok`` False on any failure). ``trace`` replaces
+    the generated request trace."""
     cfg = registry.get_config(arch)
     if args.smoke:
         cfg = registry.smoke_config(cfg)
@@ -198,9 +203,9 @@ def serve_arch(arch: str, args, serve_cfg: ServeConfig = None) -> dict:
         return serve_arch_lockstep(cfg, mesh, run, serve_cfg,
                                    args.prompt_len, args.gen)
     sampling = serve_cfg.sampling
-    if args.tenants:
+    if trace is None and args.tenants:
         trace = build_tenant_trace(args, cfg.vocab_size, sampling)
-    else:
+    elif trace is None:
         trace = build_trace(args.seed, args.requests, args.rate,
                             args.prompt_len, args.gen, cfg.vocab_size,
                             sampling)
@@ -427,7 +432,7 @@ def serve_arch(arch: str, args, serve_cfg: ServeConfig = None) -> dict:
     return s
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="default: llama3.2-3b; with --smoke and no --arch, "
@@ -545,8 +550,12 @@ def main(argv=None):
                     help="annotate trace spans with wall-clock readings "
                          "(opt-in; excluded from the deterministic trace "
                          "signature)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
     try:
         # Parse + arch-independent validation: EVERY violation in one
         # message, one non-zero exit, before any device work.

@@ -9,7 +9,10 @@ parallelism for MoE archs.
 
 --smoke uses the reduced same-family config (registry.smoke_config) so a
 ~CPU-sized model trains a few hundred steps; omit it to use the full config
-(real hardware).
+(real hardware). --n-layers cuts depth and keeps the published widths.
+
+The step is compiled ahead of the loop, so its compile time, its device
+memory (``memory_analysis``) and its HLO are known before the first step.
 """
 
 from __future__ import annotations
@@ -29,6 +32,7 @@ from repro.core.zebra_spmd import ZebraConfig
 from repro.obs import format_report, write_chrome_trace
 from repro.obs import trace as obs_trace
 from repro.data import DataConfig, DataLoader
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.models.config import ShapeConfig
@@ -37,7 +41,17 @@ from repro.train import optimizer as opt
 from repro.train.step import make_train_program
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainRun:
+    """What ``run_training`` returns: the config, the compiled step, the
+    final parameters and one record per logged step."""
+    cfg: object
+    compiled: object
+    params: object
+    history: list
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-d2")
     ap.add_argument("--steps", type=int, default=100)
@@ -45,6 +59,11 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--mesh", default="1x1", help="DATAxMODEL, e.g. 2x4")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="cut depth to this many layers (widths unchanged)")
+    ap.add_argument("--gmm-kernel", action="store_true",
+                    help="run every expert FFN on the Pallas grouped-GEMM "
+                         "kernels (default: each MoE path's own choice)")
     ap.add_argument("--zebra", action="store_true", default=True)
     ap.add_argument("--no-zebra", dest="zebra", action="store_false")
     ap.add_argument("--zebra-mode", default="replicated")
@@ -67,15 +86,25 @@ def main(argv=None):
     ap.add_argument("--trace-wall", action="store_true",
                     help="trace with wall-clock timestamps instead of the "
                          "deterministic step clock")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    enable_compile_cache()
+    run_training(parse_args(argv))
+    return 0
+
+
+def run_training(args) -> TrainRun:
     cfg = registry.get_config(args.arch)
     if args.smoke:
         cfg = registry.smoke_config(cfg)
+    if args.n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.n_layers)
     d, m = (int(x) for x in args.mesh.split("x"))
     mesh = make_mesh((d, m), ("data", "model"))
     run = RunConfig(policy=Policy(), attn_impl="chunked", moe_impl="gather",
-                    remat="full")
+                    remat="full", use_gmm_kernel=args.gmm_kernel)
     shape = ShapeConfig("cli", "train", args.seq, args.batch)
     zcfg = None
     if args.zebra and cfg.is_moe:
@@ -111,6 +140,33 @@ def main(argv=None):
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"zebra={dataclasses.asdict(program.zcfg) if program.zcfg else None}")
 
+    def batch_at_step():
+        batch = next(loader)
+        # modality-frontend stubs
+        if cfg.is_encdec:
+            batch["encoder_embeds"] = jnp.zeros(
+                (args.batch, cfg.encoder_seq, cfg.d_model),
+                run.policy.compute_dtype)
+        if cfg.vision_seq > 0:
+            batch["vision_embeds"] = jnp.zeros(
+                (args.batch, cfg.vision_seq, cfg.vision_dim or cfg.d_model),
+                run.policy.compute_dtype)
+        return jax.device_put(batch, {k: program.batch_shardings[k]
+                                      for k in batch})
+
+    batch = batch_at_step()
+    t0 = time.perf_counter()
+    with mesh:
+        compiled = program.train_step.lower(params, opt_state, batch).compile()
+    mem = compiled.memory_analysis()
+    mem_txt = "" if mem is None else (
+        f" args={mem.argument_size_in_bytes / 2**30:.2f}GiB"
+        f" temps={mem.temp_size_in_bytes / 2**30:.2f}GiB"
+        f" outputs={mem.output_size_in_bytes / 2**30:.2f}GiB"
+        f" aliased={mem.alias_size_in_bytes / 2**30:.2f}GiB")
+    print(f"[train] compiled step in {time.perf_counter() - t0:.1f}s"
+          f"{mem_txt}", flush=True)
+
     tracer = None
     last_logged: dict = {}
     if args.trace_out:
@@ -119,37 +175,34 @@ def main(argv=None):
         tracer.declare_track("train", pid="train")
         tracer.registry.register("train", lambda: dict(last_logged))
 
-    t0 = time.time()
+    history = []
+    t_log, step_log = time.perf_counter(), start_step
     for step in range(start_step, args.steps):
         if tracer is not None:
             tracer.advance(step)
-        batch = next(loader)
-        # modality-frontend stubs
-        extra_in = {}
-        if cfg.is_encdec:
-            extra_in["encoder_embeds"] = jnp.zeros(
-                (args.batch, cfg.encoder_seq, cfg.d_model),
-                run.policy.compute_dtype)
-        if cfg.vision_seq > 0:
-            extra_in["vision_embeds"] = jnp.zeros(
-                (args.batch, cfg.vision_seq, cfg.vision_dim or cfg.d_model),
-                run.policy.compute_dtype)
+        if step > start_step:
+            batch = batch_at_step()
         with mesh, obs_trace.TRACER.span("train", f"step {step}", step=step):
-            params, opt_state, metrics = program.train_step(
-                params, opt_state, {**batch, **extra_in})
+            params, opt_state, metrics = compiled(params, opt_state, batch)
         if (step + 1) % args.log_every == 0 or step == start_step:
-            dt = (time.time() - t0) / max(step - start_step + 1, 1)
-            print(f"step {step + 1:5d} loss={float(metrics['loss']):.4f} "
-                  f"nll={float(metrics['nll']):.4f} "
-                  f"gnorm={float(metrics['grad_norm']):.3f} "
-                  f"lr={float(metrics['lr']):.2e} {dt * 1e3:.0f} ms/step",
+            rec = {k: float(metrics[k])
+                   for k in ("loss", "nll", "grad_norm", "lr")}
+            # float() waited for the step: the window since the last log
+            # holds finished steps only.
+            now = time.perf_counter()
+            rec.update(step=step + 1,
+                       ms_per_step=(now - t_log) * 1e3 / (step + 1 - step_log))
+            t_log, step_log = now, step + 1
+            history.append(rec)
+            print(f"step {step + 1:5d} loss={rec['loss']:.4f} "
+                  f"nll={rec['nll']:.4f} gnorm={rec['grad_norm']:.3f} "
+                  f"lr={rec['lr']:.2e} {rec['ms_per_step']:.1f} ms/step",
                   flush=True)
             if tracer is not None:
-                last_logged.update(step=step + 1,
-                                   loss=float(metrics["loss"]),
-                                   nll=float(metrics["nll"]),
-                                   ms_per_step=round(dt * 1e3, 1))
-                tracer.count("train", "loss", float(metrics["loss"]))
+                last_logged.update(step=step + 1, loss=rec["loss"],
+                                   nll=rec["nll"],
+                                   ms_per_step=round(rec["ms_per_step"], 1))
+                tracer.count("train", "loss", rec["loss"])
         if ckpt and (step + 1) % args.ckpt_every == 0:
             ckpt.save(step + 1, params, opt_state,
                       extra={"loader": loader.state_dict()}, blocking=False)
@@ -167,7 +220,8 @@ def main(argv=None):
         for line in format_report(obj["reproIdle"]).splitlines():
             print(f"[train] idle: {line}")
     print(f"[train] done: final loss {float(metrics['loss']):.4f}")
-    return 0
+    return TrainRun(cfg=cfg, compiled=compiled, params=params,
+                    history=history)
 
 
 def _lay_zebra_sim(tracer, cfg, args) -> None:

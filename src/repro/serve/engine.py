@@ -4,7 +4,7 @@ Two entry points:
 
 * ``make_serve_program`` / ``BatchedServer`` — the lockstep demo path: one
   scalar ``cache_index`` shared by the whole batch, whole-batch prefill,
-  greedy decode. Kept for A/B parity tests and the dry-run tooling.
+  greedy decode. Kept for A/B parity tests and the lockstep fallback.
 * ``make_continuous_program`` / ``ContinuousBatchingEngine`` — the real
   serving path (DESIGN.md §7): per-slot position vector ``[B]`` + active
   mask, chunked prefill into a batch-1 cache that is *inserted* into a

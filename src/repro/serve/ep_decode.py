@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core.asym_ea import asym_ea_place, round_robin_placement
 from repro.core.zebra_spmd import _pack, _round_up, _unpack
 from repro.models import modules
@@ -269,7 +268,8 @@ def make_ep_moe_decode(mesh: Mesh, cfg: ModelConfig, run: RunConfig,
     def moe_fn(ffn_params, x2d, mask):
         fp = {k_: ffn_params[k_]
               for k_ in ("router", "wi_gate", "wi_up", "wo", "eslot")}
-        sm = _shard_map(fn, mesh, in_specs, out_specs)
+        sm = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return sm(fp, x2d, mask)
 
     return moe_fn

@@ -1,8 +1,8 @@
 """Jitted training / serving step builders with full sharding plumbing.
 
-`TrainProgram` is the single object the launcher, the dry-run, and the tests
-share: abstract param/opt shapes, NamedShardings derived from logical axes,
-and the jitted step functions.
+`TrainProgram` is the single object the launcher and the tests share:
+abstract param/opt shapes, NamedShardings derived from logical axes, and
+the jitted step functions.
 """
 
 from __future__ import annotations

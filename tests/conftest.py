@@ -1,7 +1,6 @@
 """Test configuration: 8 emulated host devices for sharding/zebra tests.
 
-(The 512-device override is reserved for launch/dryrun.py per the brief;
-tests use a small fixed pool so meshes up to 2x4 are available.)
+Tests use a small fixed pool so meshes up to 2x4 are available.
 """
 
 import os

@@ -59,11 +59,11 @@ def test_compressed_psum_matches_mean(mesh8):
     def f(g, e):
         return comp.compressed_psum(g, e, "data")
 
-    from repro.compat import shard_map
-    out, new_err = jax.jit(shard_map(
-        f, mesh8, in_specs=({"w": P("data", None)},
-                            {"w": P("data", None)}),
-        out_specs=({"w": P(None, None)}, {"w": P("data", None)})))(grads, err)
+    out, new_err = jax.jit(jax.shard_map(
+        f, mesh=mesh8, in_specs=({"w": P("data", None)},
+                                 {"w": P("data", None)}),
+        out_specs=({"w": P(None, None)}, {"w": P("data", None)}),
+        check_vma=False))(grads, err)
     want = jnp.mean(grads["w"].reshape(2, 4, 32), axis=0)
     # each data-shard row group averaged across the 2 'data' rows
     got = out["w"][:4]

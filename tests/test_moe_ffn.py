@@ -205,7 +205,7 @@ def test_gmm_glu_tiled_matches_ref(dtype):
 # ---------------------------------------------------------------------------
 
 def _count_eqns(jaxpr, pred, acc=None):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     acc = [] if acc is None else acc
 
     def visit(v):

@@ -390,6 +390,23 @@ def test_serve_driver_exits_nonzero_on_dropped_requests(monkeypatch):
     assert serve_mod.main(["--smoke"]) == 0
 
 
+def test_serve_driver_runs_a_given_trace():
+    """serve_arch serves a caller's trace in place of its generated one:
+    prompts of whole prefill chunks all finish on the paged engine."""
+    from repro.launch import serve as serve_mod
+    args = serve_mod.parse_args([
+        "--arch", "mixtral-d2", "--smoke", "--paged", "--slots", "2",
+        "--prompt-len", "32", "--gen", "4", "--prefill-chunk", "16"])
+    rng = np.random.default_rng(0)
+    trace = [Request(rid=i, prompt=rng.integers(0, 200,
+                                                16 * (1 + i % 2)).tolist(),
+                     max_new_tokens=4, arrival=float(i)) for i in range(3)]
+    s = serve_mod.serve_arch(args.arch, args, trace=trace)
+    assert s["ok"] and s["n_requests"] == 3
+    assert s["n_generated_tokens"] == 12
+    assert "paged" in s
+
+
 # ---------------------------------------------------------------------------
 # Acceptance: slot lift at fixed simulated HBM
 # ---------------------------------------------------------------------------

@@ -172,7 +172,7 @@ def test_fig2b_l40s_over_t4():
 
 
 # ---------------------------------------------------------------------------
-# Small-mesh dry-run integration (the 512-device grid runs via launch/dryrun)
+# Small-mesh dry-run integration
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("arch", ["mixtral-d2", "llama3.2-3b"])

@@ -104,6 +104,52 @@ def test_training_reduces_loss(mesh4, arch):
     assert sum(losses[-5:]) / 5 < sum(losses[:5]) / 5 - 0.1, losses
 
 
+def test_train_cli_compiles_ahead_and_fits_fixed_batch(tmp_path):
+    """launch.train at a cut depth: the step is compiled before the loop,
+    every step is logged, and a one-batch token file (the same batch every
+    step) drives the loss down from about ln(vocab)."""
+    from repro.launch import train as train_cli
+    batch, seq = 4, 64
+    args = train_cli.parse_args([
+        "--arch", "mixtral-d2", "--smoke", "--n-layers", "1",
+        "--batch", str(batch), "--seq", str(seq), "--steps", "6",
+        "--log-every", "1"])
+    vocab = registry.smoke_config(registry.get_config(args.arch)).vocab_size
+    args.data = write_token_bin(str(tmp_path / "batch.bin"),
+                                batch * seq + 1, vocab)
+    res = train_cli.run_training(args)
+    assert res.cfg.n_layers == 1
+    assert [h["step"] for h in res.history] == [1, 2, 3, 4, 5, 6]
+    losses = [h["loss"] for h in res.history]
+    assert all(np.isfinite(losses))
+    assert abs(losses[0] - np.log(vocab)) < 1.0, losses
+    assert losses[-1] < losses[0], losses
+    assert res.compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """The entry points' cache follows JAX_COMPILATION_CACHE_DIR (left to
+    JAX, nothing set) and is <checkout>/.jax_cache otherwise."""
+    from repro.launch import cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir is None
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            got = cache.enable_compile_cache()
+            assert got == jax.config.jax_compilation_cache_dir
+            root = cache.DEFAULT_DIR.parent
+            assert got == str(root / ".jax_cache")
+            assert (root / "pyproject.toml").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 # ---------------------------------------------------------------------------
 # Checkpointing
 # ---------------------------------------------------------------------------
