@@ -59,10 +59,6 @@ class SimResult:
     attn_util: float
     exp_util: float
     starts: Dict
-    # Task end times (same keys as starts). Optional so older pickled /
-    # hand-built results keep working; obs.zebra.sim_to_trace needs it to
-    # lay the schedule out as spans on a simulated timeline.
-    ends: Dict = dataclasses.field(default_factory=dict)
 
     @property
     def attn_bubble(self) -> float:
@@ -151,7 +147,6 @@ def simulate(sched: S.ZebraSchedule, times: LayerTimes, comm: CommTimes,
         attn_util=attn_busy / total if total else 0.0,
         exp_util=exp_busy / total if total else 0.0,
         starts=start,
-        ends=end,
     )
 
 

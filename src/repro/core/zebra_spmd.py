@@ -48,6 +48,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.models import modules
 from repro.models.config import LayerSpec, ModelConfig
 from repro.models.modules import RunConfig
+from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,19 +194,22 @@ def make_ep_moe(mesh: Mesh, cfg: ModelConfig, run: RunConfig,
         def fn(ffn, x):  # x: [T_loc, d] (replicated over ep axis)
             T = x.shape[0]
             weights, idx, aux = local_route(ffn["router"], x)
-            my = jax.lax.axis_index(ep)
-            e_off = my * E_loc
-            local = (idx >= e_off) & (idx < e_off + E_loc)
-            idx_loc = jnp.where(local, idx - e_off, E_loc)  # E_loc = drop
             C = max(_round_up(int(T * k / E * zcfg.capacity_factor), 8), 8)
-            buf, meta = _pack(x, idx_loc, E_loc + 1, C)
-            out = _experts_dense(ffn["wi_gate"], ffn["wi_up"], ffn["wo"],
-                                 buf[:E_loc], cd,
-                                 use_kernel=run.use_gmm_kernel)
-            out = jnp.concatenate(
-                [out, jnp.zeros((1, C, x.shape[1]), out.dtype)], axis=0)
-            y = _unpack(out, meta, weights, T)
-            y = jax.lax.psum(y, ep)  # combine partial expert outputs
+            with obs_trace.scope("dispatch"):
+                my = jax.lax.axis_index(ep)
+                e_off = my * E_loc
+                local = (idx >= e_off) & (idx < e_off + E_loc)
+                idx_loc = jnp.where(local, idx - e_off, E_loc)  # E_loc = drop
+                buf, meta = _pack(x, idx_loc, E_loc + 1, C)
+            with obs_trace.scope("experts"):
+                out = _experts_dense(ffn["wi_gate"], ffn["wi_up"], ffn["wo"],
+                                     buf[:E_loc], cd,
+                                     use_kernel=run.use_gmm_kernel)
+            with obs_trace.scope("combine"):
+                out = jnp.concatenate(
+                    [out, jnp.zeros((1, C, x.shape[1]), out.dtype)], axis=0)
+                y = _unpack(out, meta, weights, T)
+                y = jax.lax.psum(y, ep)  # combine partial expert outputs
             return y, aux
 
     else:  # alltoall: chunked, double-buffered packed-domain dispatch
@@ -232,55 +236,61 @@ def make_ep_moe(mesh: Mesh, cfg: ModelConfig, run: RunConfig,
             # each dispatch chunk covers Qc/Q of them.
             C, Cqc = kops.chunk_capacity(C0, Qc)
             Cq = C // Q
-            buf, meta = _pack(x, idx, E, C)  # [E, C, d] — packed domain
-            loc = buf[:n_loc]                # local (offloaded) experts
-            rem = buf[n_loc:].reshape(n_ep, E_loc, C, d)
-            # Dispatch: one all-to-all per capacity chunk, all issued
-            # before any expert GEMM — chunk q+1's exchange has no data
-            # dependence on chunk q's compute, so the collectives hide
-            # behind expert compute instead of preceding it (the backward
-            # of this unrolled loop transposes chunk-by-chunk and keeps
-            # the same independence, mirroring the overlap).
-            recv = [jax.lax.all_to_all(
-                        jax.lax.dynamic_slice_in_dim(rem, q * Cq, Cq, axis=2),
-                        ep, split_axis=0, concat_axis=0, tiled=False)
-                    for q in range(Q)]
+            with obs_trace.scope("dispatch"):
+                buf, meta = _pack(x, idx, E, C)  # [E, C, d] — packed
+                loc = buf[:n_loc]                # local (offloaded) experts
+                rem = buf[n_loc:].reshape(n_ep, E_loc, C, d)
+                # Dispatch: one all-to-all per capacity chunk, all issued
+                # before any expert GEMM — chunk q+1's exchange has no data
+                # dependence on chunk q's compute, so the collectives hide
+                # behind expert compute instead of preceding it (the
+                # backward of this unrolled loop transposes chunk-by-chunk
+                # and keeps the same independence, mirroring the overlap).
+                recv = [jax.lax.all_to_all(
+                            jax.lax.dynamic_slice_in_dim(rem, q * Cq, Cq,
+                                                         axis=2),
+                            ep, split_axis=0, concat_axis=0, tiled=False)
+                        for q in range(Q)]
             outs = []
             for q in range(Q):
-                r = jnp.swapaxes(recv[q], 0, 1).reshape(E_loc, n_ep * Cq, d)
-                if q == 0 and n_loc:
-                    # Local + remote experts in ONE grouped GEMM per
-                    # projection direction: the offloaded experts' GEMM
-                    # fills the bubble while later chunks are in flight.
-                    out_l, o = kops.moe_ffn_packed_multi(
-                        [loc, r],
-                        [ffn["wi_gate_loc"].astype(cd),
-                         ffn["wi_gate"].astype(cd)],
-                        [ffn["wi_up_loc"].astype(cd),
-                         ffn["wi_up"].astype(cd)],
-                        [ffn["wo_loc"].astype(cd), ffn["wo"].astype(cd)],
-                        use_kernel=uk)
-                else:
-                    o = remote_ffn(ffn, r)
+                with obs_trace.scope("experts"):
+                    r = jnp.swapaxes(recv[q], 0, 1).reshape(E_loc,
+                                                            n_ep * Cq, d)
+                    if q == 0 and n_loc:
+                        # Local + remote experts in ONE grouped GEMM per
+                        # projection direction: the offloaded experts' GEMM
+                        # fills the bubble while later chunks are in flight.
+                        out_l, o = kops.moe_ffn_packed_multi(
+                            [loc, r],
+                            [ffn["wi_gate_loc"].astype(cd),
+                             ffn["wi_gate"].astype(cd)],
+                            [ffn["wi_up_loc"].astype(cd),
+                             ffn["wi_up"].astype(cd)],
+                            [ffn["wo_loc"].astype(cd), ffn["wo"].astype(cd)],
+                            use_kernel=uk)
+                    else:
+                        o = remote_ffn(ffn, r)
                 # Combine: chunk q's reverse all-to-alls are issued before
                 # chunk q+1's GEMM — same hiding on the way back, at the
                 # FINER combine granularity (Qc/Q sub-chunks per dispatch
                 # chunk): the backward transposes these into the f32
                 # cotangent dispatch, whose 2x volume is why combine
                 # defaults to twice the dispatch chunk count.
-                o = jnp.swapaxes(o.reshape(E_loc, n_ep, Cq, d), 0, 1)
-                for s in range(Qc // Q):
-                    outs.append(jax.lax.all_to_all(
-                        o[:, :, s * Cqc:(s + 1) * Cqc], ep, split_axis=0,
-                        concat_axis=0, tiled=False))
-            back = outs[0] if len(outs) == 1 else \
-                jnp.concatenate(outs, axis=2)
-            out_full = back.reshape(E_rem, C, d)
-            if n_loc:
-                # Combine consumes ONE packed [E, C, d] output.
-                out_full = jnp.concatenate([out_l.astype(out_full.dtype),
-                                            out_full], axis=0)
-            y = _unpack(out_full, meta, weights, T)
+                with obs_trace.scope("combine"):
+                    o = jnp.swapaxes(o.reshape(E_loc, n_ep, Cq, d), 0, 1)
+                    for s in range(Qc // Q):
+                        outs.append(jax.lax.all_to_all(
+                            o[:, :, s * Cqc:(s + 1) * Cqc], ep, split_axis=0,
+                            concat_axis=0, tiled=False))
+            with obs_trace.scope("combine"):
+                back = outs[0] if len(outs) == 1 else \
+                    jnp.concatenate(outs, axis=2)
+                out_full = back.reshape(E_rem, C, d)
+                if n_loc:
+                    # Combine consumes ONE packed [E, C, d] output.
+                    out_full = jnp.concatenate(
+                        [out_l.astype(out_full.dtype), out_full], axis=0)
+                y = _unpack(out_full, meta, weights, T)
             return y, aux
 
     in_specs = (ffn_specs, batch_spec)

@@ -123,6 +123,7 @@ def flash_forward(q, k, v, *, scale, causal, window, softcap,
 
     return pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(B, H, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
@@ -248,6 +249,7 @@ def flash_backward(q, k, v, o, lse, do, *, scale, causal, window,
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           window=window, q_len=q_len, kv_len=kv_len, n_k=n_k),
+        name="flash_dq",
         grid=(B, H, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
@@ -276,6 +278,7 @@ def flash_backward(q, k, v, o, lse, do, *, scale, causal, window,
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           window=window, q_len=q_len, kv_len=kv_len, n_q=n_q),
+        name="flash_dkv",
         grid=(B, H, n_k, n_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, ik, iq: (b, h, iq, 0)),

@@ -66,6 +66,7 @@ def gmm_tiled(lhs, rhs, tile_group, *, block_m=128, block_k=128, block_n=128,
 
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, n_k=n_k),
+        name="gmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_m, n_n, n_k),
@@ -219,6 +220,7 @@ def _gmm_glu_call(lhs, rhs_g, rhs_u, tile_group, u_off, N, *, block_m,
 
     out = pl.pallas_call(
         functools.partial(_gmm_glu_kernel, n_k=n_k),
+        name="gmm_glu",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_m, n_n, n_k),
@@ -297,6 +299,7 @@ def gmm_dw_tiled(lhs, dout, tile_group, n_groups, *, block_m=128, block_k=128,
 
     drhs = pl.pallas_call(
         functools.partial(_dw_kernel, n_m=n_m),
+        name="gmm_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_k, n_n, n_m),

@@ -123,6 +123,7 @@ def paged_decode_forward(q, k_pool, v_pool, page_table, q_pos, *, scale,
                           lambda b, jp, pt, vl, qp: (b, 0, 0, 0))
     return pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, MP),

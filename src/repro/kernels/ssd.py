@@ -79,6 +79,7 @@ def ssd_pallas(xbar, la, B, C, n_heads: int, *, chunk=128, interpret=False):
 
     y, state = pl.pallas_call(
         functools.partial(_ssd_kernel, n_chunks=n_chunks),
+        name="ssd",
         grid=(BH, n_chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, hd), lambda bh, c: (bh, c, 0)),

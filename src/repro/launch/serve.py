@@ -222,7 +222,8 @@ def serve_arch(arch: str, args, serve_cfg: ServeConfig = None,
     tracer = None
     if trace_out:
         # Tick-clock tracing (DESIGN.md §15): installed process-wide so
-        # every instrumented hot path emits; off by default (NullTracer).
+        # every instrumented hot path emits; by default spans go to the
+        # profiler alone (ProfilerTracer).
         tracer = obs_trace.Tracer(
             wall=bool(getattr(args, "trace_wall", False)))
         obs_trace.install(tracer)

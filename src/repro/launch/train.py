@@ -182,7 +182,7 @@ def run_training(args) -> TrainRun:
             tracer.advance(step)
         if step > start_step:
             batch = batch_at_step()
-        with mesh, obs_trace.TRACER.span("train", f"step {step}", step=step):
+        with mesh, obs_trace.TRACER.span("train", "step", step=step):
             params, opt_state, metrics = compiled(params, opt_state, batch)
         if (step + 1) % args.log_every == 0 or step == start_step:
             rec = {k: float(metrics[k])
@@ -211,8 +211,6 @@ def run_training(args) -> TrainRun:
                   extra={"loader": loader.state_dict()})
         ckpt.wait()
     if tracer is not None:
-        if program.zcfg is not None:
-            _lay_zebra_sim(tracer, cfg, args)
         obj = write_chrome_trace(tracer, args.trace_out)
         obs_trace.install(None)
         print(f"[train] trace: {len(obj['traceEvents'])} events "
@@ -222,31 +220,6 @@ def run_training(args) -> TrainRun:
     print(f"[train] done: final loss {float(metrics['loss']):.4f}")
     return TrainRun(cfg=cfg, compiled=compiled, params=params,
                     history=history)
-
-
-def _lay_zebra_sim(tracer, cfg, args) -> None:
-    """Lay the analytic zebra timeline (core.simulator over the canonical
-    schedule, reference A40/V100 ZP pair) onto seconds-domain tracks next
-    to the measured step clock. The zebra SPMD overlap itself is scheduled
-    inside XLA, so this simulated view — the paper's own validation
-    instrument — is what carries the per-stream / a2a-exposed breakdown."""
-    from repro.core import hardware as HW
-    from repro.core import schedule as S
-    from repro.core.profiler import ZPGroupShape, profile_layer
-    from repro.core.simulator import CommTimes, simulate
-    from repro.obs.zebra import sim_to_trace
-
-    zp = ZPGroupShape(M=1, N=1, attn_class=HW.A40, exp_class=HW.V100)
-    link_bw = min(zp.attn_class.link_bw, zp.exp_class.link_bw)
-    times = profile_layer(cfg, zp, args.batch, args.seq, args.microbatches,
-                          link_bw=link_bw)
-    sched = S.canonical_schedule(cfg.n_layers, args.microbatches,
-                                 n_chunks=max(args.n_chunks, 1))
-    res = simulate(sched, times, CommTimes(times.t_dispatch, times.t_combine),
-                   cfg.n_experts, zp.N, zp.M)
-    sim_to_trace(sched, res, tracer)
-    print(f"[train] zebra-sim: iter={res.iter_time * 1e3:.2f} ms "
-          f"attn_util={res.attn_util:.2f} exp_util={res.exp_util:.2f}")
 
 
 if __name__ == "__main__":

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import LayerSpec, ModelConfig
+from repro.obs import trace as obs_trace
 from repro.pytree import (Param, fan_in_init, ones_init, zeros_init)
 
 # ---------------------------------------------------------------------------
@@ -608,24 +609,26 @@ def init_moe(key, cfg: ModelConfig):
 
 def moe_route(router_w, cfg: ModelConfig, policy: Policy, x2d):
     """Router in f32: returns (weights [T,k], idx [T,k] int32, aux dict)."""
-    logits = jnp.einsum("td,de->te", x2d.astype(policy.accum_dtype),
-                        router_w.astype(policy.accum_dtype))
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, idx = jax.lax.top_k(probs, cfg.top_k)
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    # Switch-style load-balance loss + router z-loss. The assignment
-    # fraction f is a histogram of the (non-differentiable) top-k indices:
-    # an O(T·k) bincount, not an O(T·E) one_hot materialization.
-    T = x2d.shape[0]
-    counts = jnp.bincount(idx.reshape(-1), length=cfg.n_experts)
-    f = counts.astype(policy.accum_dtype) / (T * cfg.top_k)
-    p = jnp.mean(probs, axis=0)
-    aux = {
-        "moe_aux_loss": cfg.n_experts * jnp.sum(f * p) * cfg.router_aux_coef,
-        "moe_z_loss": jnp.mean(
-            jnp.square(jax.nn.logsumexp(logits, axis=-1))) * cfg.router_z_coef,
-    }
-    return weights, idx.astype(jnp.int32), aux
+    with obs_trace.scope("router"):
+        logits = jnp.einsum("td,de->te", x2d.astype(policy.accum_dtype),
+                            router_w.astype(policy.accum_dtype))
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, idx = jax.lax.top_k(probs, cfg.top_k)
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        # Switch-style load-balance loss + router z-loss. The assignment
+        # fraction f is a histogram of the (non-differentiable) top-k
+        # indices: an O(T·k) bincount, not an O(T·E) one_hot.
+        T = x2d.shape[0]
+        counts = jnp.bincount(idx.reshape(-1), length=cfg.n_experts)
+        f = counts.astype(policy.accum_dtype) / (T * cfg.top_k)
+        p = jnp.mean(probs, axis=0)
+        z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+        aux = {
+            "moe_aux_loss":
+                cfg.n_experts * jnp.sum(f * p) * cfg.router_aux_coef,
+            "moe_z_loss": z * cfg.router_z_coef,
+        }
+        return weights, idx.astype(jnp.int32), aux
 
 
 def expert_ffn(wi_gate, wi_up, wo, xs, group_sizes, run: RunConfig,
@@ -677,15 +680,19 @@ def apply_moe(params, cfg: ModelConfig, run: RunConfig, x):
     # The router combine weight rides into the FFN as a fused row scale,
     # so the unpack gather emits already-weighted rows and the combine is
     # a bare segment-sum (one touch per output row).
-    flat_idx = idx.reshape(-1)  # [T*k]
-    sort = jnp.argsort(flat_idx)
-    tok = sort // k
-    xs = jnp.take(x2d, tok, axis=0)
-    group_sizes = jnp.bincount(flat_idx, length=cfg.n_experts).astype(jnp.int32)
-    w_sorted = jnp.take(weights.reshape(-1), sort, axis=0).astype(cd)
-    ys = expert_ffn(params["wi_gate"], params["wi_up"], params["wo"], xs,
-                    group_sizes, run, row_scales=w_sorted)
-    y = jax.ops.segment_sum(ys, tok, num_segments=T)
+    with obs_trace.scope("dispatch"):
+        flat_idx = idx.reshape(-1)  # [T*k]
+        sort = jnp.argsort(flat_idx)
+        tok = sort // k
+        xs = jnp.take(x2d, tok, axis=0)
+        group_sizes = jnp.bincount(flat_idx,
+                                   length=cfg.n_experts).astype(jnp.int32)
+        w_sorted = jnp.take(weights.reshape(-1), sort, axis=0).astype(cd)
+    with obs_trace.scope("experts"):
+        ys = expert_ffn(params["wi_gate"], params["wi_up"], params["wo"], xs,
+                        group_sizes, run, row_scales=w_sorted)
+    with obs_trace.scope("combine"):
+        y = jax.ops.segment_sum(ys, tok, num_segments=T)
     return y.reshape(B, S, d), aux
 
 
@@ -910,10 +917,11 @@ def apply_mixer_part(params, cfg: ModelConfig, run: RunConfig, spec: LayerSpec,
             window = cfg.window if spec.mixer == "local_attn" else 0
             causal = cfg.causal if spec.causal is None else spec.causal
             cache = state.get("kv") if state is not None else None
-            att, new_kv = apply_attention(
-                params["mixer"], cfg, run, u, positions, causal=causal,
-                window=window, cache=cache, cache_index=cache_index,
-                attend_to_cache=attend_to_cache, page_table=page_table)
+            with obs_trace.scope("attention"):
+                att, new_kv = apply_attention(
+                    params["mixer"], cfg, run, u, positions, causal=causal,
+                    window=window, cache=cache, cache_index=cache_index,
+                    attend_to_cache=attend_to_cache, page_table=page_table)
             if new_state is not None:
                 new_state["kv"] = new_kv
             mixed = att
@@ -932,9 +940,10 @@ def apply_mixer_part(params, cfg: ModelConfig, run: RunConfig, spec: LayerSpec,
         h = x + mixed
     if spec.cross_attn:
         u = apply_norm(params["xnorm"], h, run.policy)
-        xa, _ = apply_attention(params["xattn"], cfg, run, u, positions,
-                                causal=False, kv=encoder_out,
-                                kv_positions=encoder_positions)
+        with obs_trace.scope("attention"):
+            xa, _ = apply_attention(params["xattn"], cfg, run, u, positions,
+                                    causal=False, kv=encoder_out,
+                                    kv_positions=encoder_positions)
         gate = jnp.tanh(params["xgate"]).astype(h.dtype)
         h = h + gate * xa
     return h, new_state

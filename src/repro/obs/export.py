@@ -4,13 +4,13 @@ Writes the object form of the Chrome trace-event format
 (``{"traceEvents": [...], ...}``), which both chrome://tracing and
 ui.perfetto.dev load directly. Mapping:
 
-* tracer pids ("serve", "fleet", "zebra-sim", "train") -> trace processes,
+* tracer pids ("serve", "fleet", "train") -> trace processes,
   named via ``process_name`` metadata events;
 * tracks -> threads within their pid, named via ``thread_name`` metadata,
   ordered by declaration (``thread_sort_index``);
 * spans -> complete "X" events (B/E pairs are joined here via the explicit
-  parent eid, so out-of-order simulated timelines export correctly and a
-  dangling open span — a crash mid-span — is closed at the trace horizon);
+  parent eid, and a dangling open span — a crash mid-span — is closed at
+  the trace horizon);
 * instants -> "i" (thread scope), flows -> "s"/"t"/"f" sharing ``id``
   per request, counters -> "C".
 

@@ -12,6 +12,10 @@ the event timeline.
 Providers are lazy: ``register(name, fn)`` stores a zero-arg callable and
 ``snapshot()`` invokes them all, so registering costs nothing per tick and
 the values are read exactly when asked for (end of run, or on demand).
+
+``PROCESS`` is the process-wide registry: while a profile is being taken,
+each span of the default tracer adds its seconds and a count under
+``repro.<name>.s`` and ``repro.<name>.n`` (``obs.trace.host_span``).
 """
 
 from __future__ import annotations
@@ -62,3 +66,6 @@ class Registry:
             except Exception as e:  # pragma: no cover - defensive
                 out[name] = {"error": f"{type(e).__name__}: {e}"}
         return out
+
+
+PROCESS = Registry()

@@ -1,26 +1,35 @@
-"""Deterministic span/event tracer on the engines' tick clock (§15).
+"""Two tracers behind one ``span`` call (§15).
 
-Every serving engine in this repo already carries an integer tick counter
+The default tracer, ``ProfilerTracer``, puts spans on the profiler's clock:
+``span(track, name)`` opens ``jax.profiler.TraceAnnotation("repro.<name>")``
+while a profile is being taken (``jax.profiler.start_trace``), so the span
+lands on the host plane of the same ``.xplane.pb`` as the device's ops, and
+adds its duration and a count to the process-wide ``obs.registry.PROCESS``
+(``repro.<name>.s``, ``repro.<name>.n``). When no profile is taken it costs
+one ``TraceAnnotation.is_enabled()`` check. Every other method is inert.
+``host_span(name)`` is the same span for sites that speak to the profiler
+alone.
+
+``Tracer``, installed by the tests and by ``--trace-out`` in the launch
+drivers, runs on the engines' integer tick clock instead
 (``ContinuousBatchingEngine.tick_count``, ``DisaggController.tick_count``,
-``FleetController.tick_count``); the tracer adopts that counter as its time
-base, so a trace is a pure function of the request trace + seeds — two runs
-of the same seeded workload produce bit-identical event sequences (the same
-determinism contract ``ft.chaos.FaultInjector.log_signature`` keeps for
-fault logs). Wall-clock readings are OPT-IN annotations (``wall=True``)
-layered on top; they never participate in ordering or idle attribution.
+``FleetController.tick_count``), so a trace is a pure function of the
+request trace + seeds — two runs of the same seeded workload produce
+bit-identical event sequences (the same determinism contract
+``ft.chaos.FaultInjector.log_signature`` keeps for fault logs). Wall-clock
+readings are OPT-IN annotations (``wall=True``) layered on top; they never
+participate in ordering or idle attribution. One tick is ``TICK_US``
+microseconds of Perfetto time; events within a tick are separated by a
+per-tick emission counter, so intra-tick ordering in the viewer is exactly
+emission order.
 
-Timestamps: one tick is ``TICK_US`` microseconds of Perfetto time; events
-within a tick are separated by a per-tick emission counter, so intra-tick
-ordering in the viewer is exactly emission order. Simulated timelines
-(``obs.zebra``) use seconds-domain tracks instead (``span_at``); the two
-domains live under different pids and never mix arithmetic.
+Hot paths call ``trace.TRACER.span(...)`` at call time. Nothing in either
+tracer touches RNG state or engine control flow, so tracing cannot perturb
+tokens (tests assert bit-identical outputs either way).
 
-Disabled-by-default, zero cost when off: the module-level ``TRACER`` is a
-``NullTracer`` whose methods are empty; hot paths call
-``trace.TRACER.begin(...)`` unconditionally and pay one attribute lookup +
-one no-op call per event when tracing is off. Nothing in the tracer touches
-RNG state or engine control flow, so enabling it cannot perturb tokens
-(tests assert bit-identical outputs either way).
+``scope(name)`` names the ops traced inside a jitted function
+(``jax.named_scope``): metadata only, the compiled program is otherwise
+the same (tests lower with and without it).
 """
 
 from __future__ import annotations
@@ -30,12 +39,16 @@ import dataclasses
 import time as _time
 from typing import Dict, List, Optional, Tuple
 
+import jax
+
+from repro.obs import registry as _registry
+
 TICK_US = 1_000_000  # one engine tick == 1s of Perfetto time
 
 #: Idle-attribution buckets (§15): every idle tick of every track lands in
 #: exactly one of these, so per track sum(buckets) == ticks - busy exactly.
-IDLE_BUCKETS = ("queue-starved", "pool-OOM", "a2a-exposed", "transfer-wait",
-                "drain", "fault-stall")
+IDLE_BUCKETS = ("queue-starved", "pool-OOM", "transfer-wait", "drain",
+                "fault-stall")
 
 
 @dataclasses.dataclass
@@ -51,16 +64,44 @@ class Event:
     track: str
     name: str
     ts: float
-    tick: Optional[int]
+    tick: int
     args: dict
     eid: int
     parent: Optional[int]   # eid of the innermost open span (flows/instants)
     flow_id: Optional[int]  # request id for s/t/f events
 
 
-class NullTracer:
-    """The disabled tracer: every method is an inert stub so instrumented
-    hot paths cost one no-op call when tracing is off."""
+_annotation = jax.profiler.TraceAnnotation
+_OFF = contextlib.nullcontext()
+
+
+@contextlib.contextmanager
+def _profiled(name: str):
+    """``name`` as a profiler annotation, timed into ``registry.PROCESS``."""
+    t0 = _time.perf_counter()
+    try:
+        with _annotation(name):
+            yield
+    finally:
+        _registry.PROCESS.inc(name + ".s", _time.perf_counter() - t0)
+        _registry.PROCESS.inc(name + ".n")
+
+
+def host_span(name: str):
+    """Span ``repro.<name>`` on the profiler's clock while a profile is
+    being taken; otherwise a shared no-op context."""
+    return _profiled("repro." + name) if _annotation.is_enabled() else _OFF
+
+
+def scope(name: str):
+    """Name the ops traced inside (``jax.named_scope``). Every scope of the
+    model, the train step and the zebra engine goes through here."""
+    return jax.named_scope(name)
+
+
+class ProfilerTracer:
+    """The default tracer: ``span`` is ``host_span``; every other method is
+    an inert stub, so instrumented hot paths cost one no-op call."""
 
     __slots__ = ()
     enabled = False
@@ -77,9 +118,8 @@ class NullTracer:
     def end(self, track, **args):
         pass
 
-    @contextlib.contextmanager
     def span(self, track, name, **args):
-        yield
+        return host_span(name)
 
     def instant(self, track, name, **args):
         pass
@@ -93,24 +133,22 @@ class NullTracer:
     def mark_idle(self, track, bucket, **args):
         pass
 
-    def span_at(self, track, name, t0, t1, **args):
-        pass
-
     def busy_this_tick(self, track):
         return False
 
 
-NULL = NullTracer()
+DEFAULT = ProfilerTracer()
 
 #: The current tracer. Hot paths read ``trace.TRACER`` at call time (never
 #: ``from ... import TRACER``, which would freeze the binding).
-TRACER = NULL
+TRACER = DEFAULT
 
 
 def install(tracer) -> None:
-    """Install ``tracer`` as the process-wide current tracer (None -> off)."""
+    """Install ``tracer`` as the process-wide current tracer (None -> the
+    default)."""
     global TRACER
-    TRACER = tracer if tracer is not None else NULL
+    TRACER = tracer if tracer is not None else DEFAULT
 
 
 def current():
@@ -175,10 +213,8 @@ class Tracer:
     def declare_track(self, track: str, pid: str = "serve",
                       kind: str = "tick", sort: Optional[int] = None):
         """Register track metadata. ``kind``: "tick" (engine tick clock,
-        idle-attributed per tick), "time" (simulated seconds), "comm"
-        (simulated link stream — overlap with its spans classifies a gap
-        as a2a-exposed), "meta" (control-plane, excluded from the idle
-        report)."""
+        idle-attributed per tick) or "meta" (control-plane, excluded from
+        the idle report)."""
         if track not in self.tracks:
             self.tracks[track] = {"pid": pid, "kind": kind,
                                   "sort": len(self.tracks) if sort is None
@@ -286,16 +322,6 @@ class Tracer:
         """Whether ``track`` opened/closed any span during the current
         tick (controllers use this to decide which groups to mark idle)."""
         return self._last_busy.get(track) == self._now
-
-    # -- simulated-time spans (obs.zebra) ---------------------------------
-
-    def span_at(self, track: str, name: str, t0: float, t1: float,
-                **args) -> None:
-        """Complete span on a seconds-domain track (simulated timelines).
-        ``t0``/``t1`` are seconds; stored as Perfetto microseconds."""
-        self._ensure(track)
-        b = self._emit("B", track, name, t0 * 1e6, None, args)
-        self._emit("E", track, name, t1 * 1e6, None, {}, parent=b.eid)
 
     # -- introspection ----------------------------------------------------
 

@@ -688,42 +688,50 @@ class ContinuousBatchingEngine:
     # -- one engine tick ----------------------------------------------------
 
     def tick(self) -> None:
-        tr = obs_trace.TRACER
-        if self.owns_clock:
-            tr.advance(self.tick_count)
-        worked = False
-        budget = self.sched.token_budget
-        while budget > 0:
-            chunk = self.sched.plan_prefill(budget)
-            if chunk is None:
-                break
-            with tr.span(self.track, "prefill", rid=chunk.request.rid,
-                         start=chunk.start, length=chunk.length):
-                if chunk.first:
-                    tr.flow(self.track, "prefill", chunk.request.rid)
-                self._run_prefill_chunk(chunk)
-            worked = True
-            budget -= chunk.length
-        if self.p.paged:
-            self._ensure_pages()
-        if self._active.any():
-            with tr.span(self.track, "decode",
-                         n_active=int(self._active.sum())):
-                self._decode_once()
-            worked = True
-        if tr.enabled:
-            tr.count(self.track, "queue_depth", self.sched.queue_depth)
-            if not worked:
-                bucket = "pool-OOM" \
-                    if self.sched.prefill.wait_reason == "pages" \
-                    else "queue-starved"
-                tr.mark_idle(self.track, bucket)
-        self.metrics.on_tick(self.sched.queue_depth, self.sched.n_active)
-        if self.p.paged:
-            in_use = self.sched.allocator.pages_in_use
-            self.page_peak = max(self.page_peak, in_use)
-            self._page_ticks.append((in_use, self.sched.n_active))
-        self.tick_count += 1
+        """One tick, on the profiler's clock (``obs.trace``) the span
+        ``repro.tick``: ``schedule`` (prefill planning, page bookkeeping),
+        ``prefill`` (one chunk's dispatch), ``admit`` (sample, insert),
+        ``decode`` (the decode dispatch), ``sync`` (each blocking read of a
+        device result) and ``emit`` (the per-slot token loop) inside it."""
+        with obs_trace.host_span("tick"):
+            tr = obs_trace.TRACER
+            if self.owns_clock:
+                tr.advance(self.tick_count)
+            worked = False
+            budget = self.sched.token_budget
+            while budget > 0:
+                with obs_trace.host_span("schedule"):
+                    chunk = self.sched.plan_prefill(budget)
+                if chunk is None:
+                    break
+                with tr.span(self.track, "prefill", rid=chunk.request.rid,
+                             start=chunk.start, length=chunk.length):
+                    if chunk.first:
+                        tr.flow(self.track, "prefill", chunk.request.rid)
+                    self._run_prefill_chunk(chunk)
+                worked = True
+                budget -= chunk.length
+            if self.p.paged:
+                with obs_trace.host_span("schedule"):
+                    self._ensure_pages()
+            if self._active.any():
+                with tr.span(self.track, "decode",
+                             n_active=int(self._active.sum())):
+                    self._decode_once()
+                worked = True
+            if tr.enabled:
+                tr.count(self.track, "queue_depth", self.sched.queue_depth)
+                if not worked:
+                    bucket = "pool-OOM" \
+                        if self.sched.prefill.wait_reason == "pages" \
+                        else "queue-starved"
+                    tr.mark_idle(self.track, bucket)
+            self.metrics.on_tick(self.sched.queue_depth, self.sched.n_active)
+            if self.p.paged:
+                in_use = self.sched.allocator.pages_in_use
+                self.page_peak = max(self.page_peak, in_use)
+                self._page_ticks.append((in_use, self.sched.n_active))
+            self.tick_count += 1
 
     def _run_prefill_chunk(self, chunk: PrefillChunk) -> None:
         req = chunk.request
@@ -739,9 +747,10 @@ class ContinuousBatchingEngine:
             # [start, start+length) — any SHARED page in that range must
             # be COW-forked before the scatter lands (a resumed mid-page
             # prefill into a cached partial tail is the canonical case).
-            self._cow_guard(req.rid, chunk.start, chunk.length)
-            ptrow = jnp.asarray(self.sched.allocator.table(
-                req.rid, self.p.max_pages))[None, :]
+            with obs_trace.host_span("schedule"):
+                self._cow_guard(req.rid, chunk.start, chunk.length)
+                ptrow = jnp.asarray(self.sched.allocator.table(
+                    req.rid, self.p.max_pages))[None, :]
             with self.p.mesh:
                 self.state, self.prec, logits = self.p.prefill_step(
                     self.params, self.state, self.prec, toks,
@@ -765,7 +774,7 @@ class ContinuousBatchingEngine:
         makes the continuation token-exact (§7.4)."""
         req, slot = chunk.request, chunk.slot
         sp = req.sampling
-        with self.p.mesh:
+        with obs_trace.host_span("admit"), self.p.mesh:
             first = self.p.sample_step(
                 last_logits, np.asarray([req.rid], np.int32),
                 np.asarray([chunk.n_done], np.int32),
@@ -782,12 +791,15 @@ class ContinuousBatchingEngine:
                 self.state = self.p.insert_step(self.state, self.pstate,
                                                 jnp.asarray(slot, jnp.int32))
                 self.pstate = None
-        first = int(np.asarray(first)[0])
+            with obs_trace.host_span("sync"):
+                first = int(np.asarray(first)[0])
+                if self.record_logits:
+                    row = np.asarray(last_logits)[0]
         if self.record_logits:
             if chunk.n_done == 0:
-                self.logits[req.rid] = [np.asarray(last_logits)[0]]
+                self.logits[req.rid] = [row]
             else:
-                self.logits[req.rid].append(np.asarray(last_logits)[0])
+                self.logits[req.rid].append(row)
         self.metrics.on_token(req.rid, self.tick_count)
         finished = self.sched.activate(chunk, first)
         if self.on_token:
@@ -886,26 +898,28 @@ class ContinuousBatchingEngine:
             self._on_ep_counts(counts)
         else:
             self.state, nxt, logits = out
-        nxt = np.asarray(nxt)
-        if self.record_logits:
-            logits = np.asarray(logits)
-        for slot in np.nonzero(self._active)[0]:
-            slot = int(slot)
-            tok = int(nxt[slot])
-            rid = int(self._rid[slot])
+        with obs_trace.host_span("sync"):
+            nxt = np.asarray(nxt)
             if self.record_logits:
-                self.logits[rid].append(logits[slot])
-            self.metrics.on_token(rid, self.tick_count)
-            finished = self.sched.note_token(slot, tok)
-            if self.on_token:
-                self.on_token(rid, tok, finished)
-            if finished:
-                self.metrics.on_finish(rid, self.tick_count)
-                self._release(slot)
-            else:
-                self._tok[slot] = tok
-                self._pos[slot] += 1
-                self._ngen[slot] += 1
+                logits = np.asarray(logits)
+        with obs_trace.host_span("emit"):
+            for slot in np.nonzero(self._active)[0]:
+                slot = int(slot)
+                tok = int(nxt[slot])
+                rid = int(self._rid[slot])
+                if self.record_logits:
+                    self.logits[rid].append(logits[slot])
+                self.metrics.on_token(rid, self.tick_count)
+                finished = self.sched.note_token(slot, tok)
+                if self.on_token:
+                    self.on_token(rid, tok, finished)
+                if finished:
+                    self.metrics.on_finish(rid, self.tick_count)
+                    self._release(slot)
+                else:
+                    self._tok[slot] = tok
+                    self._pos[slot] += 1
+                    self._ngen[slot] += 1
 
     def _on_ep_counts(self, counts) -> None:
         """Routing-histogram hook (EP decode): overridden by

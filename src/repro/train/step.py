@@ -19,6 +19,7 @@ from repro.core import zebra_spmd
 from repro.models import stack
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.models.modules import RunConfig
+from repro.obs import trace as obs_trace
 from repro.pytree import split_params, tree_map_with_path_names
 from repro.sharding.rules import ShardingRules, rules_for, specs_for
 from repro.train import optimizer as opt
@@ -151,9 +152,10 @@ def make_train_program(cfg: ModelConfig, mesh: Mesh, run: RunConfig,
             layer_override=override, return_hidden=True)
         table = params.get("lm_head", params["embed"]["table"])
         from repro.train.loss import chunked_xent_from_hidden
-        loss, metrics = chunked_xent_from_hidden(
-            hidden, table.astype(run.policy.compute_dtype),
-            batch["targets"], unroll=cfg.unroll, constrain=run.constrain)
+        with obs_trace.scope("head"):
+            loss, metrics = chunked_xent_from_hidden(
+                hidden, table.astype(run.policy.compute_dtype),
+                batch["targets"], unroll=cfg.unroll, constrain=run.constrain)
         loss = loss + aux.get("moe_aux_loss", 0.0) + aux.get("moe_z_loss", 0.0)
         metrics = dict(metrics, **aux, loss=loss)
         return loss, metrics
@@ -192,8 +194,9 @@ def make_train_program(cfg: ModelConfig, mesh: Mesh, run: RunConfig,
             # optimizer: turns XLA's full-size gradient all-reduce into
             # reduce-scatter (+ sharded elementwise update).
             grads = jax.lax.with_sharding_constraint(grads, psh)
-        params, opt_state, om = opt.adamw_update(opt_cfg, params, grads,
-                                                 opt_state)
+        with obs_trace.scope("optimizer"):
+            params, opt_state, om = opt.adamw_update(opt_cfg, params, grads,
+                                                     opt_state)
         metrics.update(om)
         return params, opt_state, metrics
 

@@ -8,9 +8,8 @@ chaos replay gives bit-identical trace signatures), the exact idle
 accounting identity ``sum(buckets) == ticks - busy`` on a REAL
 fleet-under-chaos run whose exported trace carries spans from the
 scheduler, engine, KV transfer, fleet controller and chaos injector plus
-request flows crossing group tracks, and the a2a-exposed bucket of a
-simulated zebra timeline reconciling against ``simulator.exposed_comm``
-within 10%.
+request flows crossing group tracks. The default tracer's spans on the
+profiler's clock are covered in ``tests/test_obs_profiler.py``.
 """
 
 import json
@@ -19,15 +18,13 @@ import jax
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import schedule as S
-from repro.core.profiler import LayerTimes
-from repro.core.simulator import CommTimes, chaos_matrix, simulate
+from repro.core.simulator import chaos_matrix
 from repro.ft.chaos import FaultInjector, FaultPlan
 from repro.models import stack
+from repro.obs import registry as obs_registry
 from repro.obs import trace as obs_trace
 from repro.obs.export import to_chrome
 from repro.obs.report import idle_report
-from repro.obs.zebra import sim_to_trace
 from repro.pytree import split_params
 from repro.serve.fleet import make_fleet
 from repro.serve.metrics import ServeMetrics
@@ -259,7 +256,7 @@ def test_tracing_disabled_is_bit_identical(mesh1, tiny_params):
     tr, traced, _ = _traced_fleet_run(mesh1, tiny_params,
                                       _STANDARD_SPEC, seed=3)
     assert tr.events  # the traced run actually recorded something
-    assert obs_trace.TRACER is obs_trace.NULL  # use() uninstalled it
+    assert obs_trace.TRACER is obs_trace.DEFAULT  # use() uninstalled it
     inj = FaultInjector(FaultPlan.parse(_STANDARD_SPEC), seed=3)
     fleet = _fleet(mesh1, tiny_params, chaos=inj)
     fleet.router.slow_factor = lambda name: 1.0
@@ -294,51 +291,6 @@ def test_unified_engine_idle_attribution(mesh1, tiny_params):
 
 
 # ---------------------------------------------------------------------------
-# Acceptance: simulated zebra timeline — a2a-exposed vs simulator
-# ---------------------------------------------------------------------------
-
-def test_zebra_a2a_exposed_reconciles_with_simulator():
-    """ACCEPTANCE: on a comm-dominant zebra schedule the attention
-    stream's a2a-exposed idle matches the union of exposed link busy time
-    (simulator.exposed_comm prices the link tasks) within 10%, and
-    chunked overlap shrinks both."""
-    times = LayerTimes(t_attn=0.05, t_exp=0.05, t_exp_attn=0.05,
-                       t_exp_on_exp=0.05, t_attn_on_exp=0.4)
-    comm = CommTimes(dispatch=1.0, combine=1.0)
-    sched = S.canonical_schedule(4, 3, n_chunks=1)
-    res = simulate(sched, times, comm, 4, 1, 1)
-    tr = obs_trace.Tracer()
-    sim_to_trace(sched, res, tr)
-    rep = idle_report(tr)
-
-    ivals = sorted((res.starts[t], res.ends[t])
-                   for s in ("link_a2e", "link_e2a")
-                   for t in sched.streams[s] if res.ends[t] > res.starts[t])
-    merged = []
-    for t0, t1 in ivals:
-        if merged and t0 <= merged[-1][1]:
-            merged[-1] = (merged[-1][0], max(merged[-1][1], t1))
-        else:
-            merged.append((t0, t1))
-    exposed_union = sum(t1 - t0 for t0, t1 in merged)
-
-    a2a = rep["zebra:attn_comp"]["buckets"]["a2a-exposed"]
-    assert abs(a2a - exposed_union) / exposed_union < 0.10
-    # the time-track identity holds too (report self-check)
-    for r in rep.values():
-        assert r["_check"]
-
-    # overlap (n_chunks=4) shrinks the exposed residue AND the bucket
-    sched4 = S.canonical_schedule(4, 3, n_chunks=4)
-    res4 = simulate(sched4, times, comm, 4, 1, 1)
-    tr4 = obs_trace.Tracer()
-    sim_to_trace(sched4, res4, tr4)
-    rep4 = idle_report(tr4)
-    assert res4.iter_time < res.iter_time
-    assert rep4["zebra:attn_comp"]["buckets"]["a2a-exposed"] < a2a
-
-
-# ---------------------------------------------------------------------------
 # Exporter + registry plumbing
 # ---------------------------------------------------------------------------
 
@@ -364,17 +316,25 @@ def test_export_embeds_registry_and_counters():
 
 
 def test_null_tracer_is_inert():
-    """Disabled-path contract: NULL absorbs every call, reports not-busy,
-    and the span context manager still runs the body."""
-    n = obs_trace.NULL
+    """Default-path contract: with no profile being taken, DEFAULT absorbs
+    every call, reports not-busy, tallies nothing, and the span context
+    manager still runs the body."""
+    n = obs_trace.DEFAULT
+    assert obs_trace.TRACER is n
     assert not n.enabled
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    before = obs_registry.PROCESS.snapshot()
     n.advance(5)
     n.begin("t", "x")
     n.end("t")
     n.flow("t", "queued", 1)
+    n.count("t", "queue_depth", 3)
     n.mark_idle("t", "queue-starved")
     ran = []
     with n.span("t", "x"):
         ran.append(True)
-    assert ran and n.busy_this_tick("t") is False
+    with obs_trace.host_span("y"):
+        ran.append(True)
+    assert ran == [True, True] and n.busy_this_tick("t") is False
+    assert obs_registry.PROCESS.snapshot() == before
     assert idle_report(n) == {}

@@ -14,6 +14,7 @@ imports this module. All such compiles stay in this one file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,17 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernels(text: str) -> set:
+    """Names of the Mosaic kernels in compiled HLO text: each
+    ``pallas_call(name=...)`` becomes the name of its custom-call, which
+    is what a profile's op events carry; autodiff wraps it
+    (``transpose_jvp_flash_dq__``)."""
+    return {re.sub(r"^(?:transpose_|jvp_)+", "", m.group(1)).rstrip("_")
+            for m in re.finditer(
+                r"%([A-Za-z_]+)(?:\.\d+)? = [^\n]*custom_call_target="
+                r'"tpu_custom_call"', text)}
+
+
 def test_moe_ffn_fwd_and_grad_compile(one_chip):
     M = 4 * 2048 * 2  # train-phase rows: 4 x 2048 tokens, top-2
     bf = jnp.bfloat16
@@ -75,9 +87,13 @@ def test_moe_ffn_fwd_and_grad_compile(one_chip):
     def loss(x, wg, wu, wo, gs):
         return jnp.sum(fwd(x, wg, wu, wo, gs).astype(jnp.float32) ** 2)
 
-    assert "tpu_custom_call" in _compiled_text(fwd, x, wg, wu, wo, gs)
+    text = _compiled_text(fwd, x, wg, wu, wo, gs)
+    assert "tpu_custom_call" in text
+    assert _kernels(text) == {"gmm_glu", "gmm"}
     grad = jax.grad(loss, argnums=(0, 1, 2, 3))
-    assert "tpu_custom_call" in _compiled_text(grad, x, wg, wu, wo, gs)
+    text = _compiled_text(grad, x, wg, wu, wo, gs)
+    assert "tpu_custom_call" in text
+    assert {"gmm_glu", "gmm", "gmm_dw"} <= _kernels(text)
 
 
 @pytest.mark.parametrize("kv_heads", [2, 4, 8])
@@ -93,8 +109,9 @@ def test_paged_decode_compile(one_chip, kv_heads):
         return ops.paged_decode_attention(q, k, v, table, q_pos,
                                           use_kernel=True, interpret=False)
 
-    assert "tpu_custom_call" in _compiled_text(decode, q, pool, pool, table,
-                                               q_pos)
+    text = _compiled_text(decode, q, pool, pool, table, q_pos)
+    assert "tpu_custom_call" in text
+    assert _kernels(text) == {"paged_decode"}
 
 
 def test_flash_fwd_and_bwd_compile(one_chip):
@@ -109,6 +126,10 @@ def test_flash_fwd_and_bwd_compile(one_chip):
     def loss(q, k, v):
         return jnp.sum(fwd(q, k, v).astype(jnp.float32) ** 2)
 
-    assert "tpu_custom_call" in _compiled_text(fwd, q, kv, kv)
+    text = _compiled_text(fwd, q, kv, kv)
+    assert "tpu_custom_call" in text
+    assert _kernels(text) == {"flash_fwd"}
     grad = jax.grad(loss, argnums=(0, 1, 2))
-    assert "tpu_custom_call" in _compiled_text(grad, q, kv, kv)
+    text = _compiled_text(grad, q, kv, kv)
+    assert "tpu_custom_call" in text
+    assert _kernels(text) == {"flash_fwd", "flash_dq", "flash_dkv"}
