@@ -3,7 +3,8 @@
 One fused sampler covers greedy, temperature, top-k and nucleus (top-p)
 sampling: every slot selects its own behaviour from per-slot parameter
 vectors, so a batch mixing greedy and sampled requests still decodes in a
-single compiled program.
+single compiled program. The sorted domain, values and permutation both,
+comes from one stable key-value sort; nothing gathers over the vocabulary.
 
 Determinism contract: the PRNG key for request ``rid``'s ``n``-th
 generated token is ``fold_in(fold_in(base_key, rid), n)`` — a function of
@@ -19,6 +20,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,10 +48,13 @@ def sample_tokens(logits, keys, temperature, top_k, top_p):
     logits: [B, V] (any float dtype); keys: [B] PRNG keys (request_keys);
     temperature/top_p: [B] f32; top_k: [B] i32. Returns [B] int32.
 
-    Filtering runs in the sorted domain (descending logits): top-k keeps
-    rank < k; top-p keeps the smallest prefix whose mass reaches p (the
-    head token always survives, so the result is never empty); the pick is
-    a Gumbel-max over the surviving entries, mapped back through the sort
+    Filtering runs in the sorted domain (descending logits), which one
+    stable key-value sort of ``(-scaled, iota)`` yields whole: the sorted
+    values and the permutation, ties in index order as ``jnp.argsort``
+    breaks them, with no gather over the vocabulary. Top-k keeps rank < k;
+    top-p keeps the smallest prefix whose mass reaches p (the head token
+    always survives, so the result is never empty); the pick is a
+    Gumbel-max over the surviving entries, mapped back through the sort
     permutation.
     """
     V = logits.shape[-1]
@@ -58,8 +63,9 @@ def sample_tokens(logits, keys, temperature, top_k, top_p):
         lg = lg.astype(jnp.float32)
         greedy = t <= 0.0
         scaled = lg / jnp.maximum(t, 1e-6)
-        order = jnp.argsort(-scaled)  # descending
-        vals = scaled[order]
+        neg, order = lax.sort_key_val(-scaled, lax.iota(jnp.int32, V),
+                                      is_stable=True)  # descending
+        vals = -neg
         rank = jnp.arange(V)
         keep = rank < jnp.where(k <= 0, V, k)
         probs = jax.nn.softmax(vals)
