@@ -15,11 +15,13 @@ the numbers ``bench/run.py`` compares, read for:
   float8 (e4m3, per-tensor scale), the precision below the configuration's
   bfloat16, against the float32 reference;
 * ``fault``: training, ``half_batch``: the reference on the first half of
-  each batch's rows, the mean taken over them, against the whole batch (a
-  step that returns its state unchanged reads 1 on the gradient by the
-  measure itself, and needs no run); serving, ``altered_token``: every
-  sampled served token replaced by the next vocabulary id, read against
-  the reference as a served token is.
+  each batch's rows, the mean taken over them, against the whole batch;
+  and ``dropped_shard``: the reference with the experts of one chip (the
+  last of the mesh's expert axis) adding nothing, as where that chip's
+  exchange is left out (a step that returns its state unchanged reads 1
+  on the gradient by the measure itself, and needs no run); serving,
+  ``altered_token``: every sampled served token replaced by the next
+  vocabulary id, read against the reference as a served token is.
 
 Serving reads the control and the fault on the program's own sample, so
 it always runs the program's window first.
@@ -49,9 +51,9 @@ def train_readings(cell, seed: int, read=("program", "control", "fault"),
     from repro.data import DataConfig, DataLoader
 
     mix, out = cell.mix, {}
+    devices = devices or jax.devices()[:1]
     if "program" in read:
-        b = train.build(cell, devices or jax.devices()[:1],
-                        harness.Spans(False))
+        b = train.build(cell, devices, harness.Spans(False))
         st, batches, prog = train.first_steps(b, seed, mix["check_steps"])
         harness.free(st.params, st.opt_state, st.met, st.batch)
         del st
@@ -62,16 +64,19 @@ def train_readings(cell, seed: int, read=("program", "control", "fault"),
                                        global_batch=mix["batch"], seed=seed))
         batches = [{k: np.asarray(v) for k, v in next(loader).items()}
                    for _ in range(mix["check_steps"])]
-    exact = train.reference_readings(cell, seed, batches)
+    exact = train.reference_readings(cell, seed, batches, devices=devices)
 
     def keep(r):
         return {k: v for k, v in train.compare(r, exact).items() if k in KEEP}
     if "program" in read:
         out["program"] = keep(prog)
     if "control" in read:
-        out["control"] = keep(train.reference_readings(cell, seed, batches,
-                                                       precision="fp8"))
+        out["control"] = keep(train.reference_readings(
+            cell, seed, batches, precision="fp8", devices=devices))
     if "fault" in read:
+        out["dropped_shard"] = keep(train.reference_readings(
+            cell, seed, batches, devices=devices,
+            drop=train.dropped_shard(cell.config, mix)))
         half_mix = dict(mix, batch=mix["batch"] // 2)
         if mix["zebra"]["num_microbatches"] > half_mix["batch"]:
             half_mix["zebra"] = dict(mix["zebra"],
@@ -79,7 +84,7 @@ def train_readings(cell, seed: int, read=("program", "control", "fault"),
         half_cell = types.SimpleNamespace(config=cell.config, mix=half_mix)
         out["half_batch"] = keep(train.reference_readings(
             half_cell, seed, [{k: v[:half_mix["batch"]] for k, v in b.items()}
-                              for b in batches]))
+                              for b in batches], devices=devices))
     return out
 
 

@@ -25,8 +25,6 @@ import time
 import numpy as np
 
 import harness
-from flops import prefill_chunk_flops, serve_token_flops
-from reference import mixtral as ref
 from traffic import generate
 
 
@@ -45,21 +43,22 @@ def _instrument(engine, m, spans, counters, live, tracked, rows):
     (rid -> {token index: [V] or [1, V] device array})."""
     import jax
 
+    flops = harness.model_module(m, "flops")
     run_chunk, decode_once = engine._run_prefill_chunk, engine._decode_once
     admit, decode_step = engine._admit, engine.p.decode_step
     take = jax.jit(lambda x, i: x[i])
 
     def prefill(chunk):
         if live[0]:
-            counters.flops += prefill_chunk_flops(m, chunk.start,
-                                                  chunk.length)
+            counters.flops += flops.prefill_chunk_flops(m, chunk.start,
+                                                        chunk.length)
         with spans.span("prefill"):
             run_chunk(chunk)
 
     def decode():
         if live[0]:
             for r in engine.sched.running.values():
-                counters.flops += serve_token_flops(
+                counters.flops += flops.serve_token_flops(
                     m, len(r.request.prompt) + r.n_generated, True)
         with spans.span("decode"):
             decode_once()
@@ -280,7 +279,7 @@ def readings(cell, seed, pick, tokens, logits, control=None) -> dict:
     if not done:
         return {"served_logit_gap_mean": float("inf"),
                 "logit_distance": float("inf"), "tokens": 0}
-    got = ref.served_readings(
+    got = harness.model_module(m, "reference").served_readings(
         m, harness.seed32(seed), mix["engine"]["max_len"],
         mix["output"]["max"],
         [(r.prompt, tokens[r.rid], logits[r.rid]) for r in done],

@@ -171,23 +171,22 @@ def worst_leaf_gap(prog: Dict[str, float], ref: Dict[str, float],
     return worst, where
 
 
+def leaves(tree) -> dict:
+    """Pytree -> {'a/b/c': leaf}."""
+    import jax
+    return {"/".join(str(getattr(p, "key", getattr(p, "idx", p)))
+                     for p in path): x
+            for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
 def flat(tree) -> Dict[str, float]:
     """Pytree of scalars -> {'a/b/c': float}."""
-    import jax
-    out = {}
-    for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                       for p in path)
-        out[key] = float(np.asarray(x))
-    return out
+    return {k: float(np.asarray(x)) for k, x in leaves(tree).items()}
 
 
 def flat_arrays(tree) -> Dict[str, np.ndarray]:
     """Pytree of host arrays -> {'a/b/c': array}."""
-    import jax
-    return {"/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                     for p in path): np.asarray(x)
-            for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+    return {k: np.asarray(x) for k, x in leaves(tree).items()}
 
 
 def log(msg: str) -> None:
@@ -195,20 +194,25 @@ def log(msg: str) -> None:
     print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
 
-MODEL_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
-              "d_ff_expert", "vocab_size", "n_experts", "top_k",
-              "rope_theta", "capacity_factor", "router_aux_coef",
-              "router_z_coef")
-
-
 def model_config(m: dict):
     """The program's ModelConfig for a configuration file: the registry's
-    architecture with every size the file gives."""
+    architecture with every key of the file that is one of its fields."""
     import dataclasses as dc
     from repro.models import registry
     base = registry.get_config(m["arch"])
-    return dc.replace(base, name=m["name"],
-                      **{k: m[k] for k in MODEL_KEYS if k in m})
+    fields = {f.name for f in dc.fields(base)}
+    return dc.replace(base, **{k: v for k, v in m.items() if k in fields})
+
+
+def model_module(m: dict, kind: str):
+    """The configuration's own ``reference`` or ``flops`` module under
+    ``bench/``: ``reference.<name>`` (default ``mixtral``) and ``<name>``
+    (default ``flops``), as the file names them."""
+    import importlib
+    if kind == "reference":
+        return importlib.import_module(
+            "reference." + m.get("reference", "mixtral"))
+    return importlib.import_module(m.get("flops", "flops"))
 
 
 def make_mesh(shape, devices):

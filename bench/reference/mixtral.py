@@ -36,6 +36,10 @@ Semantics, and where they follow the configuration rather than Mixtral:
 control, the same with both operands rounded to float8 (e4m3, per-tensor
 scale) first, the precision below the bfloat16 the configuration computes
 in.
+
+Training runs over the devices it is given: each expert stack is split
+over them on its expert axis, every other weight is replicated, and the
+partitioner places the rest. On one device that is the plain program.
 """
 
 from __future__ import annotations
@@ -46,6 +50,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG = -1e30
@@ -194,11 +199,13 @@ def swiglu(p, x, dot, spec_in, spec_out):
     return dot(spec_out, jax.nn.silu(g) * u, p["wo"])
 
 
-def moe_capacity(p, m, x, capacity, dot):
+def moe_capacity(p, m, x, capacity, dot, drop=None):
     """One capacity group x [T, d]: each assignment goes to row
     ``expert * capacity + rank`` of a [E * capacity, d] buffer, where rank
     counts the earlier assignments to that expert in token order; an
-    assignment of rank >= capacity is dropped (adds nothing)."""
+    assignment of rank >= capacity is dropped (adds nothing). ``drop``
+    (lo, hi): experts lo..hi-1 add nothing either (a fault, for the
+    control's readings)."""
     E, k = m["n_experts"], m["top_k"]
     T, d = x.shape
     w, idx, probs, lse = route(p["router"], m, x)
@@ -206,6 +213,8 @@ def moe_capacity(p, m, x, capacity, dot):
     onehot = jax.nn.one_hot(flat, E, dtype=jnp.int32)
     rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, -1)
     keep = rank < capacity
+    if drop is not None:
+        keep = keep & ((flat < drop[0]) | (flat >= drop[1]))
     row = jnp.where(keep, flat * capacity + rank, E * capacity)
     tok = jnp.arange(T * k) // k
     buf = jnp.zeros((E * capacity + 1, d), x.dtype).at[row].set(x[tok])
@@ -237,7 +246,7 @@ def moe_dropless(p, m, x, dot, block=256):
     return jax.lax.map(one, xp).reshape(nb * block, -1)[:T]
 
 
-def layer_fwd(p, m, x, dot, groups, capacity):
+def layer_fwd(p, m, x, dot, groups, capacity, drop=None):
     """One layer over x [B, S, d]. ``groups`` > 0: capacity groups of
     contiguous tokens; 0: dropless."""
     B, S, d = x.shape
@@ -245,7 +254,7 @@ def layer_fwd(p, m, x, dot, groups, capacity):
     u = rms_norm(h, p["norm2"]["scale"]).reshape(B * S, d)
     if groups:
         ys, auxs, zs = jax.lax.map(
-            lambda g: moe_capacity(p["ffn"], m, g, capacity, dot),
+            lambda g: moe_capacity(p["ffn"], m, g, capacity, dot, drop),
             u.reshape(groups, -1, d))
         y, aux, z = ys.reshape(B * S, d), jnp.mean(auxs), jnp.mean(zs)
     else:
@@ -253,11 +262,11 @@ def layer_fwd(p, m, x, dot, groups, capacity):
     return h + y.reshape(B, S, d), aux + z
 
 
-def hidden(params, m, tokens, dot, groups=0, capacity=0):
+def hidden(params, m, tokens, dot, groups=0, capacity=0, drop=None):
     """Final-normed hidden states [B, S, d] and the summed router losses."""
     x = jnp.take(params["embed"]["table"], tokens, axis=0)
     layer = jax.checkpoint(
-        lambda x, p: layer_fwd(p, m, x, dot, groups, capacity))
+        lambda x, p: layer_fwd(p, m, x, dot, groups, capacity, drop))
     aux = 0.0
     for p in params["layers"]:
         x, a = layer(x, p)
@@ -265,9 +274,9 @@ def hidden(params, m, tokens, dot, groups=0, capacity=0):
     return rms_norm(x, params["final_norm"]["scale"]), aux
 
 
-def lm_loss(params, m, tokens, targets, dot, groups, capacity,
+def lm_loss(params, m, tokens, targets, dot, groups, capacity, drop=None,
             z_loss_coef=1e-4, chunk=512):
-    h, aux = hidden(params, m, tokens, dot, groups, capacity)
+    h, aux = hidden(params, m, tokens, dot, groups, capacity, drop)
     B, S, d = h.shape
     n = S // chunk if S % chunk == 0 else 1
     c = S // n
@@ -365,9 +374,29 @@ def program_arrays(tree) -> dict:
             for k, v in out.items()}
 
 
+def weight_shardings(m: dict, devices):
+    """Each expert stack ([E, ...] under ``ffn``) split over ``devices`` on
+    its expert axis, where E divides over them; every other weight
+    replicated."""
+    mesh = Mesh(np.asarray(devices), ("expert",))
+    split = m["n_experts"] % len(devices) == 0
+
+    def one(path, x):
+        keys = [str(getattr(q, "key", q)) for q in path]
+        if split and "ffn" in keys and x.ndim == 3:
+            return NamedSharding(mesh, P("expert"))
+        return NamedSharding(mesh, P())
+    shapes = jax.eval_shape(functools.partial(init_params, m), 0)
+    return (jax.tree_util.tree_map_with_path(one, shapes),
+            NamedSharding(mesh, P()))
+
+
 def train_readings(m: dict, o: dict, seed: int, batches, groups: int,
-                   capacity: int, precision: str = "f32"):
-    """Drive the reference through ``len(batches)`` steps from the seed.
+                   capacity: int, precision: str = "f32", devices=None,
+                   drop=None):
+    """Drive the reference through ``len(batches)`` steps from the seed,
+    over ``devices`` (default: the first device); ``drop`` is passed to
+    ``moe_capacity``.
 
     Returns (losses per step, per-leaf norms of the first clipped gradient,
     per-leaf norms of the change of the weights over all steps, the first
@@ -376,12 +405,17 @@ def train_readings(m: dict, o: dict, seed: int, batches, groups: int,
     ``program_arrays``). The gradient is read back from the first moment
     after one step over 1 - b1, as the program's is."""
     dot = make_dot(precision)
+    sh, rep = weight_shardings(m, devices or jax.devices()[:1])
     loss_grad = jax.jit(jax.value_and_grad(functools.partial(
-        lm_loss, m=m, dot=dot, groups=groups, capacity=capacity)))
-    step_fn = jax.jit(functools.partial(adamw, o), donate_argnums=(0, 2, 3))
+        lm_loss, m=m, dot=dot, groups=groups, capacity=capacity, drop=drop)),
+        out_shardings=(rep, sh))
+    step_fn = jax.jit(functools.partial(adamw, o), donate_argnums=(0, 2, 3),
+                      out_shardings=(sh, sh, sh, rep))
     diff_norms = jax.jit(lambda a, b: leaf_norms(
-        jax.tree.map(jnp.subtract, a, b)))
-    init = jax.jit(functools.partial(init_params, m))
+        jax.tree.map(jnp.subtract, a, b)), out_shardings=rep)
+    init = jax.jit(functools.partial(init_params, m), out_shardings=sh)
+    zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p),
+                    out_shardings=(sh, sh))
     seed = jnp.asarray(seed % 2**32, jnp.uint32)
 
     params = init(seed)
@@ -389,14 +423,14 @@ def train_readings(m: dict, o: dict, seed: int, batches, groups: int,
     # step needs only the weights, their gradient and its temporaries.
     moments, losses, g1, first = None, [], None, None
     for t, b in enumerate(batches, start=1):
-        loss, grads = loss_grad(params, tokens=jnp.asarray(b["tokens"]),
-                                targets=jnp.asarray(b["targets"]))
+        loss, grads = loss_grad(params,
+                                tokens=jax.device_put(b["tokens"], rep),
+                                targets=jax.device_put(b["targets"], rep))
         losses.append(float(loss))
         if moments is None:
-            mu = jax.tree.map(jnp.zeros_like, params)
-            nu = jax.tree.map(jnp.zeros_like, params)
+            mu, nu = zeros((params, params))
         else:
-            mu, nu = jax.device_put(moments)
+            mu, nu = jax.device_put(moments, (sh, sh))
         params, mu, nu, gn = step_fn(params, grads, mu, nu,
                                      jnp.asarray(t, jnp.float32))
         del grads

@@ -1,5 +1,6 @@
 """Shared fixtures: a scratch checkout holding a copy of the benchmark, the
-program's sources and one tiny cell of each driver, run on the CPU."""
+program's sources and one tiny cell of each driver, run on the CPU, with 8
+emulated devices for the expert-parallel cells."""
 
 from __future__ import annotations
 
@@ -10,7 +11,9 @@ import pathlib
 import shutil
 import sys
 
-import pytest
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+
+import pytest  # noqa: E402
 
 REPO = pathlib.Path(__file__).resolve().parents[2]
 if str(REPO / "bench") not in sys.path:
@@ -34,6 +37,9 @@ TINY_TRAIN = {
                   "weight_decay": 0.1, "grad_clip": 1.0},
     "check_steps": 3,
 }
+TINY_A2A = dict(
+    TINY_TRAIN, batch=8, mesh=[1, 4],
+    zebra={"mode": "alltoall", "num_microbatches": 2, "n_chunks": 2})
 TINY_SERVE = {
     "driver": "serve", "shape_seed": 0,
     "arrivals": {"kind": "poisson", "rate_per_s": 8.0},
@@ -46,6 +52,8 @@ TINY_SERVE = {
 }
 LIMITS = {"tiny-train": {"loss_gap": 0.02, "grad_norm_gap": 0.02,
                          "update_norm_gap": 0.02, "grad_diff": 0.2},
+          "tiny-a2a": {"loss_gap": 0.02, "grad_norm_gap": 0.02,
+                       "update_norm_gap": 0.02, "grad_diff": 0.2},
           "tiny-serve": {"served_logit_gap_mean": 0.02,
                          "logit_distance": 0.05}}
 
@@ -80,7 +88,8 @@ def _driver(root, w):
 @pytest.fixture
 def checkout(tmp_path):
     """A checkout with the benchmark copied, the program linked, and the
-    cells ``tiny-train`` and ``tiny-serve`` added."""
+    cells ``tiny-train``, ``tiny-a2a`` (four devices) and ``tiny-serve``
+    added."""
     root = tmp_path / "checkout"
     root.mkdir()
     shutil.copytree(REPO / "bench", root / "bench",
@@ -95,6 +104,8 @@ def checkout(tmp_path):
     write_json(root / "bench" / "configs" / "tiny.json", TINY)
     add_cell(root, "tiny-train", "tiny", "tiny-train", TINY_TRAIN,
              LIMITS["tiny-train"])
+    add_cell(root, "tiny-a2a", "tiny", "tiny-a2a", TINY_A2A,
+             LIMITS["tiny-a2a"], chips=4)
     add_cell(root, "tiny-serve", "tiny", "tiny-serve", TINY_SERVE,
              LIMITS["tiny-serve"])
     return root

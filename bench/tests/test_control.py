@@ -7,6 +7,7 @@ PERF.md."""
 from __future__ import annotations
 
 import jax
+import pytest
 
 from conftest import LIMITS, load_run
 
@@ -22,13 +23,16 @@ def _fails(readings: dict, limits: dict) -> bool:
     return any(readings[k] > v for k, v in limits.items())
 
 
-def test_train_control_and_half_batch_fail(checkout):
-    control, cell = _cell(checkout, "tiny-train")
+@pytest.mark.parametrize("name", ["tiny-train", "tiny-a2a"])
+def test_train_control_and_half_batch_fail(checkout, name):
+    control, cell = _cell(checkout, name)
     for seed in (1, 2, 3):
-        rec = control.train_readings(cell, seed)
-        assert not _fails(rec["program"], LIMITS["tiny-train"]), rec
-        assert _fails(rec["control"], LIMITS["tiny-train"]), rec
-        assert _fails(rec["half_batch"], LIMITS["tiny-train"]), rec
+        rec = control.train_readings(cell, seed,
+                                     devices=jax.devices()[:cell.chips])
+        assert not _fails(rec["program"], LIMITS[name]), rec
+        assert _fails(rec["control"], LIMITS[name]), rec
+        assert _fails(rec["half_batch"], LIMITS[name]), rec
+        assert _fails(rec["dropped_shard"], LIMITS[name]), rec
 
 
 def test_serve_control_and_altered_token_fail(checkout):

@@ -9,8 +9,14 @@ named ``WINDOW``, which the harness opens around its measured loop.
 * busy: the union of the intervals in which an operation ran on a chip,
   clipped to the window; averaged over the chips used;
 * idle share: 1 - busy / window;
+* all-to-all: the union of the intervals in which an all-to-all ran on a
+  chip, clipped to the window; averaged over the chips. An asynchronous
+  one runs from its ``-start`` op to the next ``-done`` op;
 * exposed all-to-all: time in which an all-to-all ran on a chip while no
-  other operation did, over the window; averaged over the chips;
+  other operation did, over the window; averaged over the chips. An op
+  whose event holds the next op's whole event (a ``while``, ``call`` or
+  ``conditional``, which spans the ops of its body) is not another
+  operation: its body's ops are events of their own;
 * idle gaps: the device's idle intervals (on the first chip), each charged
   to the innermost harness span (name starting ``bench.``) that covers its
   middle, or to ``(none)``.
@@ -48,6 +54,7 @@ class Summary:
     window_s: float
     busy_s: float                     # mean over chips
     idle_share: float                 # 1 - busy_s / window_s
+    a2a_s: Optional[float]            # mean over chips; None: no all-to-all
     a2a_exposed_s: Optional[float]    # mean over chips; None: no all-to-all
     device_ops: List[Tuple[str, float]]
     idle_gaps: List[Tuple[str, float]]
@@ -138,6 +145,30 @@ def overlap(a, b) -> float:
     return t
 
 
+def a2a_intervals(ops: List[Event]) -> List[Tuple[float, float]]:
+    """Each all-to-all's interval; an asynchronous one's from its
+    ``-start`` op to the next ``-done`` op (in the order they start)."""
+    out, started = [], []
+    for o in sorted(ops, key=lambda o: o.start):
+        if not is_all_to_all(o.name):
+            continue
+        kind = o.name.split(".")[0]
+        if kind.endswith("-start"):
+            started.append(o)
+        elif kind.endswith("-done") and started:
+            out.append((started.pop(0).start, o.end))
+        else:
+            out.append((o.start, o.end))
+    return out + [(o.start, o.end) for o in started]
+
+
+def leaf_ops(ops: List[Event]) -> List[Event]:
+    """The ops whose event holds no other op's whole event."""
+    ops = sorted(ops, key=lambda o: (o.start, -o.end))
+    return [a for a, b in zip(ops, ops[1:] + [None])
+            if b is None or b.start >= a.end or b.end > a.end]
+
+
 def exposed(a2a, others, lo: float, hi: float) -> float:
     """Time inside the a2a intervals that no other interval covers."""
     mine = union(a2a, lo, hi)
@@ -167,7 +198,7 @@ def summarize(tr: Trace, top: int = 10) -> Optional[Summary]:
     win = hi - lo
     if not tr.devices:
         return None
-    busy_each, a2a_each = [], []
+    busy_each, a2a_each, exposed_each = [], [], []
     op_time: Dict[str, float] = collections.defaultdict(float)
     first = sorted(tr.devices)[0]
     first_busy = []
@@ -175,11 +206,12 @@ def summarize(tr: Trace, top: int = 10) -> Optional[Summary]:
         ops = tr.devices[plane]
         busy = union([(o.start, o.end) for o in ops], lo, hi)
         busy_each.append(total(busy))
-        a2a = [(o.start, o.end) for o in ops if is_all_to_all(o.name)]
+        a2a = a2a_intervals(ops)
         if a2a:
-            rest = [(o.start, o.end) for o in ops
-                    if not is_all_to_all(o.name)]
-            a2a_each.append(exposed(a2a, rest, lo, hi))
+            rest = [(o.start, o.end) for o in leaf_ops(
+                [o for o in ops if not is_all_to_all(o.name)])]
+            a2a_each.append(total(union(a2a, lo, hi)))
+            exposed_each.append(exposed(a2a, rest, lo, hi))
         for o in ops:
             d = min(o.end, hi) - max(o.start, lo)
             if d > 0:
@@ -188,12 +220,15 @@ def summarize(tr: Trace, top: int = 10) -> Optional[Summary]:
             first_busy = busy
     busy_s = sum(busy_each) / len(busy_each) / 1e9
     idle = charge(gaps(first_busy, lo, hi), tr.host_spans)
+
+    def per_chip(each):
+        return sum(each) / len(tr.devices) / 1e9 if each else None
     return Summary(
         window_s=win / 1e9,
         busy_s=busy_s,
         idle_share=1.0 - busy_s / (win / 1e9),
-        a2a_exposed_s=(sum(a2a_each) / len(tr.devices) / 1e9
-                       if a2a_each else None),
+        a2a_s=per_chip(a2a_each),
+        a2a_exposed_s=per_chip(exposed_each),
         device_ops=sorted(((k, v / 1e9) for k, v in op_time.items()),
                           key=lambda kv: -kv[1])[:top],
         idle_gaps=sorted(((k, v / 1e9) for k, v in idle.items()),
