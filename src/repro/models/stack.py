@@ -325,6 +325,51 @@ def apply_model(params, cfg: ModelConfig, run: RunConfig, tokens,
 
 
 # ---------------------------------------------------------------------------
+# Compute view (serving)
+# ---------------------------------------------------------------------------
+
+# Leaves the forward reads only as ``leaf.astype(policy.compute_dtype)``: the
+# matmul weights, the embedding table and the head. Every other leaf keeps
+# the param dtype, because the forward reads it in f32 or it is small:
+#   router                  moe_route computes its logits in the accum dtype
+#   scale, bias             norms apply them in the accum dtype
+#   q_norm, k_norm          headwise RMSNorm, accum dtype
+#   w_i, w_a, b_i, b_a, lam the RG-LRU gates, computed in f32
+#   conv_w, conv_b          cast to the activations' dtype, not the policy's
+#   A_log, dt_bias, D, norm SSD's decay, step, skip and gated norm (f32 math)
+#   xgate, pos              a tanh gate in f32; learned positions (small)
+#   eslot                   the EP decode placement map (int32)
+COMPUTE_LEAVES = frozenset({
+    "wq", "wk", "wv", "wo",                # attention; also the FFNs' "wo"
+    "wi_gate", "wi_up", "wi", "bi", "bo",  # dense and expert FFNs
+    "table", "lm_head", "vision_proj",     # embedding, head, VLM projector
+    "proj_gate", "proj_rec", "out",        # RG-LRU projections
+    "in_proj", "out_proj",                 # SSD projections
+})
+
+
+def is_compute_leaf(path, leaf) -> bool:
+    """Whether ``compute_view`` holds this leaf (a tree path from
+    ``jax.tree_util``) in the compute dtype."""
+    key = path[-1] if path else None
+    return (isinstance(key, jax.tree_util.DictKey)
+            and key.key in COMPUTE_LEAVES
+            and jnp.issubdtype(leaf.dtype, jnp.floating))
+
+
+def compute_view(params, policy: Policy):
+    """``params`` with every leaf of ``COMPUTE_LEAVES`` stored in
+    ``policy.compute_dtype``: what a serving engine holds. The forward
+    gives bit-identical numbers on it, and no step re-reads the f32
+    masters to cast them. A leaf already in the compute dtype (a policy
+    computing in f32) is returned as it is. Training never takes this
+    view: its step updates the f32 masters."""
+    cd = jnp.dtype(policy.compute_dtype)
+    return jax.tree_util.tree_map_with_path(
+        lambda p, v: v.astype(cd) if is_compute_leaf(p, v) else v, params)
+
+
+# ---------------------------------------------------------------------------
 # Decode state
 # ---------------------------------------------------------------------------
 

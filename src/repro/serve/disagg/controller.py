@@ -32,12 +32,10 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional
 
-import jax
-
 from repro.models.config import ModelConfig
 from repro.models.modules import RunConfig
 from repro.obs import trace as obs_trace
-from repro.serve.engine import make_continuous_program
+from repro.serve.engine import compute_params, make_continuous_program
 from repro.serve.kv_blocks import BlockAllocator
 from repro.serve.kv_transfer import KVTransferEngine, TransferAbortedError
 from repro.serve.metrics import ServeMetrics
@@ -263,9 +261,9 @@ def make_disagg(cfg: ModelConfig, mesh, run: RunConfig, params, *,
         if pl is None:
             pl = round_robin_placement(cfg.n_experts, ep.ep_size)
         params = place_params(params, cfg, pl)
-    with mesh:
-        pre_params = jax.device_put(params, pre_prog.param_shardings)
-        dec_params = jax.device_put(params, dec_prog.param_shardings)
+    # One compute view per program, shared by every worker built on it.
+    pre_params = compute_params(pre_prog, params)
+    dec_params = compute_params(dec_prog, params)
     caching = prefix is not None and getattr(prefix, "enabled", False)
     pre_sched = PrefillScheduler(
         max_len, prefill_chunk=prefill_chunk, token_budget=token_budget,

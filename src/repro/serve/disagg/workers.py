@@ -41,7 +41,7 @@ import jax
 
 from repro.models import stack
 from repro.obs import trace as obs_trace
-from repro.serve.engine import ContinuousProgram
+from repro.serve.engine import ContinuousProgram, compute_params
 from repro.serve.kv_transfer import KVTransferEngine
 from repro.serve.metrics import ServeMetrics
 from repro.serve.scheduler import (DecodeScheduler, PrefillScheduler,
@@ -73,7 +73,7 @@ class PrefillWorker:
         assert sched.allocator is not None, \
             "prefill scheduler needs the prefill pool's allocator"
         self.p = program
-        self.params = params
+        self.params = compute_params(program, params)
         self.sched = sched
         self.track = "prefill"  # tracer track (§15); fleet renames per group
         sched.track = self.track
@@ -151,7 +151,7 @@ class DecodeWorker:
             and sched.allocator.max_pages_per_seq >= program.max_pages, \
             "allocator geometry disagrees with the program"
         self.p = program
-        self.params = params
+        self.params = compute_params(program, params)
         self.sched = sched
         self.track = "decode"  # tracer track (§15); fleet renames per group
         sched.track = self.track

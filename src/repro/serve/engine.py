@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models import stack
 from repro.models.config import ModelConfig, ShapeConfig
+from repro.obs import registry as obs_registry
 from repro.obs import trace as obs_trace
 from repro.models.modules import RunConfig
 from repro.serve import sampling
@@ -84,6 +85,36 @@ def decode_state_specs(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules,
         lambda: stack.init_decode_state(cfg, batch, max_len, dtype))
     from repro.pytree import tree_map_with_path_names
     return state_shapes, tree_map_with_path_names(spec_for, state_shapes)
+
+
+def compute_params(program, params):
+    """The parameters a serving engine holds: ``stack.compute_view`` of
+    ``params``, made once by one program whose outputs take the serving
+    program's param shardings, so that no prefill or decode step casts a
+    weight again. A tree with nothing to cast (a policy computing in f32,
+    or a view already) is only placed. The f32 masters stay the caller's:
+    an engine never writes its view back as weights.
+
+    Sets the gauges ``serve.params_bytes.compute`` (bytes of the leaves
+    the view holds for the matmuls) and ``serve.params_bytes.master``
+    (bytes of every other leaf) in ``obs.registry.PROCESS``."""
+    policy = program.run.policy
+    cd = jnp.dtype(policy.compute_dtype)
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    with program.mesh:
+        if any(stack.is_compute_leaf(p, v) and v.dtype != cd
+               for p, v in leaves):
+            params = jax.jit(
+                functools.partial(stack.compute_view, policy=policy),
+                out_shardings=program.param_shardings)(params)
+        else:
+            params = jax.device_put(params, program.param_shardings)
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    compute = sum(v.nbytes for p, v in leaves if stack.is_compute_leaf(p, v))
+    obs_registry.PROCESS.set("serve.params_bytes.compute", compute)
+    obs_registry.PROCESS.set("serve.params_bytes.master",
+                             sum(v.nbytes for _, v in leaves) - compute)
+    return params
 
 
 @dataclasses.dataclass
@@ -178,7 +209,7 @@ class BatchedServer:
     def __init__(self, program: ServeProgram, params, batch: int,
                  max_len: int):
         self.p = program
-        self.params = params
+        self.params = compute_params(program, params)
         self.batch = batch
         self.max_len = max_len
         cfg, run = program.cfg, program.run
@@ -630,7 +661,7 @@ class ContinuousBatchingEngine:
                  scheduler: Scheduler, *, metrics: ServeMetrics = None,
                  on_token: Callable = None, record_logits: bool = False):
         self.p = program
-        self.params = params
+        self.params = compute_params(program, params)
         self.sched = scheduler
         self.metrics = metrics or ServeMetrics()
         self.on_token = on_token  # callable(rid, token, finished)

@@ -39,7 +39,8 @@ from repro.core.zebra_spmd import _pack, _round_up, _unpack
 from repro.models import modules
 from repro.models.config import ModelConfig
 from repro.models.modules import RunConfig
-from repro.serve.engine import ContinuousBatchingEngine, ContinuousProgram
+from repro.serve.engine import (ContinuousBatchingEngine, ContinuousProgram,
+                                compute_params)
 from repro.serve.metrics import RoutingEMA
 from repro.serve.scheduler import Scheduler
 
@@ -371,12 +372,13 @@ class EPContinuousBatchingEngine(ContinuousBatchingEngine):
     """Continuous batching over EP-sharded expert weights (DESIGN.md §11).
 
     Takes UNPLACED (replicated-layout) params: placement happens here —
-    permute + inject ``eslot`` + device_put under the program's EP param
-    shardings. Every decode step returns the routed-copy histogram, which
-    feeds a :class:`RoutingEMA`; when ``rebalance_every`` is set and the
-    EMA drifts past ``drift_threshold`` (total variation vs the histogram
-    the current placement was computed from), experts are re-placed via
-    ``placer`` (a callable hist -> placement; defaults to load-balanced
+    permute + inject ``eslot``, then the compute view under the program's
+    EP param shardings (``engine.compute_params``). Every decode step
+    returns the routed-copy histogram, which feeds a :class:`RoutingEMA`;
+    when ``rebalance_every`` is set and the EMA drifts past
+    ``drift_threshold`` (total variation vs the histogram the current
+    placement was computed from), experts are re-placed via ``placer``
+    (a callable hist -> placement; defaults to load-balanced
     :func:`balanced_placement`). Re-balance swaps ONLY ``self.params`` —
     KV pools, page tables and slot state are untouched, so generation
     continues token-exact across the reshuffle.
@@ -399,15 +401,14 @@ class EPContinuousBatchingEngine(ContinuousBatchingEngine):
         self.placement = tuple(tuple(int(e) for e in s) for s in pl)
         E = program.cfg.n_experts
         self._placement_hist = np.full((E,), 1.0 / E)
-        self._program = program  # _place runs before super().__init__
-        placed = self._place(self.placement)
-        super().__init__(program, placed, scheduler, **kw)
+        super().__init__(program, place_params(params, program.cfg,
+                                               self.placement),
+                         scheduler, **kw)
 
     def _place(self, placement):
-        placed = place_params(self._base_params, self._program.cfg,
-                              placement)
-        with self._program.mesh:
-            return jax.device_put(placed, self._program.param_shardings)
+        """The placed params' compute view under the EP shardings."""
+        return compute_params(self.p, place_params(self._base_params,
+                                                   self.p.cfg, placement))
 
     def _on_ep_counts(self, counts) -> None:
         self.ema.update(np.asarray(counts))

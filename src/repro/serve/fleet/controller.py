@@ -717,11 +717,9 @@ def make_fleet(cfg, mesh, run, params, *, prefill_classes: Sequence[str],
     compiled program); each group still owns its own pool state and
     allocator.
     """
-    import jax
-
     from repro.core import profiler as P
     from repro.core.hardware import CLASSES
-    from repro.serve.engine import make_continuous_program
+    from repro.serve.engine import compute_params, make_continuous_program
     from repro.serve.kv_blocks import BlockAllocator
     from repro.serve.scheduler import DecodeScheduler, PrefillScheduler
 
@@ -741,9 +739,9 @@ def make_fleet(cfg, mesh, run, params, *, prefill_classes: Sequence[str],
     dec_prog = make_continuous_program(
         cfg, mesh, run, n_slots=decode_slots, max_len=max_len, seed=seed,
         page_size=page_size, n_pages=decode_pages)
-    with mesh:
-        pre_params = jax.device_put(params, pre_prog.param_shardings)
-        dec_params = jax.device_put(params, dec_prog.param_shardings)
+    # One compute view per program, shared by every worker built on it.
+    pre_params = compute_params(pre_prog, params)
+    dec_params = compute_params(dec_prog, params)
 
     def make_prefill_worker() -> PrefillWorker:
         sched = PrefillScheduler(

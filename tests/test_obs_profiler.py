@@ -166,7 +166,10 @@ def test_spans_land_on_the_profile_nested_and_tallied(prog, params,
     tally = obs_registry.Registry()
     monkeypatch.setattr(obs_registry, "PROCESS", tally)
     _engine(prog, params).run(_requests())
-    assert tally.snapshot() == {}  # no profile: nothing tallied
+    # no profile: no span tallied, only the gauges the engine sets once
+    gauges = {k: tally.get(k) for k in ("serve.params_bytes.compute",
+                                        "serve.params_bytes.master")}
+    assert tally.snapshot() == {"scalars": gauges}
 
     _, ticks = _profiled_run(prog, params, tmp_path)
     lines = _host_spans(tmp_path)

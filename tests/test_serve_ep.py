@@ -286,3 +286,47 @@ def test_ep_engine_token_exact_across_rebalance(moe_params, trace,
         assert n < 500
     assert eng.n_rebalances == 1
     assert eng.results == ref_results
+
+
+# -- the EP engine holds the compute view ------------------------------------
+
+def test_ep_engine_holds_compute_view_across_rebalance(moe_cfg, moe_params,
+                                                       trace, ep_mesh):
+    """With a bf16 compute policy the EP engine's expert stacks are in bf16
+    after placement and after a re-balance (the view is cast after
+    placing), the router stays f32, and the served tokens equal the
+    replicated bf16 engine's."""
+    run = RunConfig(attn_impl="ref", moe_impl="gather")
+    mesh = make_mesh((1, 1), ("data", "model"))
+    prog = make_continuous_program(moe_cfg, mesh, run, n_slots=3,
+                                   max_len=24)
+    want = ContinuousBatchingEngine(
+        prog, moe_params, Scheduler(3, 24, prefill_chunk=4)).run(list(trace))
+
+    ep_prog = make_continuous_program(
+        moe_cfg, ep_mesh, run, n_slots=3, max_len=24,
+        ep=EPDecodeConfig(ep_size=2, n_chunks=2))
+    eng = EPContinuousBatchingEngine(ep_prog, moe_params,
+                                     Scheduler(3, 24, prefill_chunk=4))
+
+    def ffn_dtypes():
+        ffn = eng.params["blocks"]["pos0"]["ffn"]
+        return {k: v.dtype for k, v in ffn.items()}
+
+    placed = {"wi_gate": jnp.bfloat16, "wi_up": jnp.bfloat16,
+              "wo": jnp.bfloat16, "router": jnp.float32,
+              "eslot": jnp.int32}
+    assert ffn_dtypes() == placed
+    pending = sorted(trace, key=lambda r: r.arrival)
+    n = 0
+    while pending or eng.sched.has_work() or eng._active.any():
+        while pending and pending[0].arrival <= eng.tick_count:
+            eng.submit(pending.pop(0))
+        eng.tick()
+        n += 1
+        if n == 5:
+            assert eng.rebalance(tuple(reversed(eng.placement)))
+            assert ffn_dtypes() == placed
+        assert n < 500
+    assert eng.n_rebalances == 1
+    assert eng.results == want

@@ -12,6 +12,9 @@ after requeue), and the slot-lift acceptance: at equal simulated HBM the
 paged engine sustains >= 1.5x the reservation engine's slot count.
 """
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,8 +68,8 @@ def _ref_greedy(params, cfg, run, prompt, n, eos=None):
 
 
 def _paged_engine(cfg, mesh, params, *, n_slots, max_len, page_size=8,
-                  n_pages=None, prefill_chunk=6, **eng_kw):
-    prog = make_continuous_program(cfg, mesh, RUN, n_slots=n_slots,
+                  n_pages=None, prefill_chunk=6, run=RUN, **eng_kw):
+    prog = make_continuous_program(cfg, mesh, run, n_slots=n_slots,
                                    max_len=max_len, page_size=page_size,
                                    n_pages=n_pages)
     with mesh:
@@ -430,3 +433,173 @@ def test_paged_slot_lift_at_fixed_hbm(mesh1, tiny_params):
         f"paged engine sustained {sustained} slots at the HBM budget " \
         f"that backs {slots_ref} reserved slots"
     assert eng.page_peak <= budget_pages
+
+
+# ---------------------------------------------------------------------------
+# Compute view: the engine holds its weights in the compute dtype
+# ---------------------------------------------------------------------------
+
+RUN_BF16 = RunConfig(attn_impl="ref", moe_impl="gather")  # f32 params, bf16
+
+# mixtral-d2's layout at tiny widths; no other dim equals E, d or f, so an
+# expert stack's shape names it in the HLO.
+MIXTRAL_TINY = dataclasses.replace(
+    registry.get_config("mixtral-d2"), name="mixtral-tiny", n_layers=2,
+    d_model=64, n_heads=2, n_kv_heads=1, head_dim=32, d_ff=112,
+    d_ff_expert=112, vocab_size=96, n_experts=6)
+
+VIEW_CFGS = {
+    "moe": MIXTRAL_TINY,
+    "dense": registry.smoke_config(registry.get_config("llama3.2-3b")),
+    # RG-LRU gates and conv read in f32 / the activations' dtype
+    "rglru": registry.smoke_config(registry.get_config("recurrentgemma-9b")),
+}
+
+
+def _noisy_params(cfg, seed=0):
+    """A seeded init with noise on every float leaf, so that no leaf (a
+    norm's ones, a bias's zeros) survives a wrong cast to bf16 intact."""
+    params = split_params(stack.init_model(jax.random.PRNGKey(seed), cfg))[0]
+    leaves, tdef = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
+    return tdef.unflatten([
+        v + 0.05 * jax.random.normal(k, v.shape, v.dtype)
+        if jnp.issubdtype(v.dtype, jnp.floating) else v
+        for k, v in zip(keys, leaves)])
+
+
+def _strict(step, *args):
+    """``step(*args)`` compiled without XLA's licence to keep f32 where a
+    program rounds to bf16 (``xla_allow_excess_precision``): with it, XLA
+    may skip a rounding in one program and not in the other, and the f32
+    tree's program then differs from the view's in the last bit."""
+    return step.lower(*args).compile(
+        compiler_options={"xla_allow_excess_precision": False})(*args)
+
+
+def _prefill_then_decode(prog, params, prompt):
+    """One prefill chunk into slot 0, then one sampled decode step."""
+    B, MP = prog.n_slots, prog.max_pages
+    toks = jnp.asarray(prompt, jnp.int32)[None]
+    with prog.mesh:
+        state, prec = prog.init_state(), prog.init_prec()
+        ptabs = np.full((B, MP), -1, np.int32)
+        ptabs[0] = np.arange(MP)
+        state, prec, last = _strict(
+            prog.prefill_step, params, state, prec, toks,
+            jnp.asarray(0, jnp.int32), jnp.asarray(ptabs[:1]))
+        state = prog.insert_step(state, prec, jnp.asarray(0, jnp.int32))
+        tok = np.zeros((B, 1), np.int32)
+        tok[0, 0] = int(jnp.argmax(last[0]))
+        pos = np.full((B,), -1, np.int32)
+        pos[0] = len(prompt)
+        active = np.arange(B) == 0
+        state, nxt, logits = _strict(
+            prog.decode_step, params, state, tok, pos, ptabs, active,
+            np.arange(B, dtype=np.int32), np.ones((B,), np.int32),
+            np.full((B,), 0.8, np.float32), np.full((B,), 5, np.int32),
+            np.full((B,), 0.9, np.float32))
+    return jax.device_get((last, nxt, logits, state))
+
+
+@pytest.mark.parametrize("kind", sorted(VIEW_CFGS))
+def test_compute_view_steps_bit_identical(mesh1, kind):
+    """The program's own prefill and decode steps on the compute view give
+    the same logits, sampled tokens and KV state, bit for bit, as on the
+    f32 masters (which the steps cast themselves), and an engine run
+    serves the same tokens; the view holds exactly the matmul leaves in
+    bf16."""
+    cfg = VIEW_CFGS[kind]
+    params = _noisy_params(cfg)
+    view = stack.compute_view(params, RUN_BF16.policy)
+    is_bf16 = jax.tree_util.tree_map_with_path(
+        lambda p, v: (v.dtype == jnp.bfloat16, stack.is_compute_leaf(p, v)),
+        view)
+    for held, listed in jax.tree.leaves(
+            is_bf16, is_leaf=lambda x: isinstance(x, tuple)):
+        assert held == listed
+    prog = make_continuous_program(cfg, mesh1, RUN_BF16, n_slots=2,
+                                   max_len=32, page_size=8)
+    prompt = _prompt(7, 12, cfg.vocab_size)
+    want = _prefill_then_decode(prog, params, prompt)
+    got = _prefill_then_decode(prog, view, prompt)
+    for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+
+    reqs = [Request(rid=i, prompt=_prompt(20 + i, 9 + 3 * i, cfg.vocab_size),
+                    max_new_tokens=5,
+                    sampling=SamplingParams(temperature=0.7, top_k=8))
+            for i in range(3)]
+    served = []
+    for masters in (True, False):
+        eng = _paged_engine(cfg, mesh1, params, n_slots=2, max_len=32,
+                            prefill_chunk=8, run=RUN_BF16)
+        if masters:  # the steps read the f32 tree, as before the view
+            with mesh1:
+                eng.params = jax.device_put(params,
+                                            eng.p.param_shardings)
+        served.append(eng.run([dataclasses.replace(r) for r in reqs]))
+    assert served[0] == served[1]
+    assert sorted(served[0]) == [0, 1, 2]
+
+
+def test_compute_view_casts_nothing_when_computing_in_f32():
+    params = _noisy_params(MIXTRAL_TINY)
+    view = stack.compute_view(params, RUN.policy)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(view)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+_CONVERT = re.compile(r"stablehlo\.convert\s+%\S+\s*:\s*"
+                      r"\(tensor<([0-9x]+)xf32>\)\s*->\s*tensor<\1xbf16>")
+
+
+def _stack_converts(text, cfg):
+    """Shapes of the f32 -> bf16 converts in ``text`` that are expert
+    stacks: [L,E,d,f], [E,d,f], [E,f,d], or one layer's slice [1,E,...]."""
+    L, E = cfg.n_pattern_repeats, cfg.n_experts
+    d, f = cfg.d_model, cfg.d_ff_expert
+    stacks = {(E, d, f), (E, f, d)}
+    stacks |= {(n,) + s for s in set(stacks) for n in (1, L)}
+    shapes = [tuple(int(n) for n in m.split("x"))
+              for m in _CONVERT.findall(text)]
+    return [s for s in shapes if s in stacks]
+
+
+def test_paged_programs_hold_no_expert_stack_convert(mesh1):
+    """With the engine's view, the lowered decode and prefill programs of
+    a paged mixtral engine convert no expert stack from f32 (the f32 tree
+    does, in both); the params gauges sum to the view's bytes."""
+    from repro.obs import registry as obs_registry
+    cfg = MIXTRAL_TINY
+    params = split_params(stack.init_model(jax.random.PRNGKey(0), cfg))[0]
+    eng = _paged_engine(cfg, mesh1, params, n_slots=2, max_len=32,
+                        run=RUN_BF16)
+    prog, view = eng.p, eng.params
+    leaves = jax.tree.leaves(view)
+    bf16 = sum(v.nbytes for v in leaves if v.dtype == jnp.bfloat16)
+    reg = obs_registry.PROCESS
+    assert reg.get("serve.params_bytes.compute") == bf16 > 0
+    assert reg.get("serve.params_bytes.master") == \
+        sum(v.nbytes for v in leaves) - bf16
+
+    B, MP = prog.n_slots, prog.max_pages
+    vec_i, vec_f = np.zeros((B,), np.int32), np.zeros((B,), np.float32)
+
+    def lowered(p):
+        with mesh1:
+            dec = prog.decode_step.lower(
+                p, eng.state, np.zeros((B, 1), np.int32), vec_i,
+                np.zeros((B, MP), np.int32), np.ones((B,), bool), vec_i,
+                vec_i, vec_f, vec_i, vec_f).as_text()
+            pre = prog.prefill_step.lower(
+                p, eng.state, prog.init_prec(), np.zeros((1, 8), np.int32),
+                jnp.asarray(0, jnp.int32),
+                np.zeros((1, MP), np.int32)).as_text()
+        return dec, pre
+
+    for text in lowered(params):
+        assert _stack_converts(text, cfg)
+    for text in lowered(view):
+        assert _stack_converts(text, cfg) == []
